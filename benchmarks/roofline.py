@@ -129,12 +129,6 @@ def build_cases(p: Dict, backend: str) -> List[Dict]:
              flops=2.0 * Q * M * C * d, useful_flops=2.0 * Q * M * C * d,
              bytes_=f32 * (Q * d + M * C * d + Q * M * C)),
         lambda: ops.posting_scan(q, vecs, slot_valid, backend=backend))
-    add(_row("posting_scan_gather", "posting_scan",
-             f"Q={Q} P={P} C={C} d={d}",
-             flops=2.0 * Q * P * C * d, useful_flops=2.0 * Q * P * C * d,
-             bytes_=f32 * (Q * d + Q * P * C * d + Q * P * C)),
-        lambda: ops.posting_scan_gather(q, vecs, slot_valid, vis, probe,
-                                        backend=backend))
     add(_row("posting_scan_topk", "posting_scan",
              f"Q={Q} P={P} C={C} d={d} k={k}",
              flops=2.0 * Q * P * C * d + 1.0 * Q * P * k * C,
@@ -148,12 +142,6 @@ def build_cases(p: Dict, backend: str) -> List[Dict]:
     adc_exec = 2.0 * Q * P * m * C * ksub
     adc_useful = 2.0 * Q * P * m * C
     adc_bytes = Q * P * (m * C + f32 * m * ksub)  # codes u8 + lut tile
-    add(_row("pq_scan_gather", "pq_scan",
-             f"Q={Q} P={P} C={C} m={m} ksub={ksub}",
-             flops=adc_exec, useful_flops=adc_useful,
-             bytes_=adc_bytes + f32 * Q * P * C),
-        lambda: ops.pq_scan_gather(luts, codes, pslot, slot_valid, vis,
-                                   probe, backend=backend))
     add(_row("pq_scan_topk", "pq_scan",
              f"Q={Q} P={P} C={C} m={m} ksub={ksub} k={k}",
              flops=adc_exec + 1.0 * Q * P * k * C,
@@ -242,7 +230,7 @@ def render(rows: List[Dict]) -> str:
 def main(argv=None) -> List[Dict]:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--backend", default="auto",
-                    choices=("auto", "ref", "pallas"))
+                    choices=("auto", "off", "on"))
     ap.add_argument("--preset", default="smoke", choices=sorted(PRESETS))
     ap.add_argument("--out", default=None, help="write rows as JSON")
     ap.add_argument("--no-measure", action="store_true",

@@ -25,6 +25,8 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="bench_results.json")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import figures, figserve
     from benchmarks.common import FULL, QUICK
     scale = FULL if args.full else QUICK

@@ -59,7 +59,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import tier as tier_mod, update
 from ..core import version_manager as vm
@@ -68,7 +68,7 @@ from ..core.driver import SearchDispatch
 from ..core.sharded import (index_specs, make_sharded_background,
                             make_sharded_delete, make_sharded_exact,
                             make_sharded_insert, make_sharded_migrate,
-                            make_sharded_search)
+                            make_sharded_retrain, make_sharded_search)
 from ..core.types import STATUS_NORMAL, IndexState, UBISConfig
 from ..kernels import ops
 from ..obs import Obs
@@ -112,7 +112,12 @@ class ShardedUBISDriver:
         if seed_vectors is None:
             raise ValueError("seed_vectors required (used for k-means seeds)")
         self.cfg = cfg
-        self.mesh = mesh if mesh is not None else default_mesh(cfg)
+        mesh = mesh if mesh is not None else default_mesh(cfg)
+        # the sharded programs rely on the compiler to place plain-jit
+        # gathers and scatters, i.e. on Auto axes; ``jax.make_mesh``
+        # builds Explicit axes by default, so take the mesh as Auto
+        self.mesh = mesh.update(
+            axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         if cfg.max_postings % self.mesh.shape["model"]:
             raise ValueError("max_postings must divide the model axis")
         self.round_size = int(round_size)
@@ -177,6 +182,7 @@ class ShardedUBISDriver:
         self._shard_cache_scan = shard_cache_scan
         self._search_fns = {}
         self._exact_fns = {}
+        self._retrain_fn = None
         # queries shard over the data axes: batches pad to this multiple
         axes = self.mesh.axis_names
         qaxes = ("pod", "data") if "pod" in axes else ("data",)
@@ -589,11 +595,10 @@ class ShardedUBISDriver:
         return self.exec_pq_retrain()
 
     def exec_pq_retrain(self) -> int:
-        """Execute one codebook re-train round now.  ``retrain_round``
-        is a plain jit program: GSPMD partitions it over the existing
-        shardings; the output is re-pinned to the canonical specs so
-        later shard_map calls see exact layouts."""
-        from ..quant import pq
+        """Execute one codebook re-train round now
+        (``sharded.make_sharded_retrain``); the output is re-pinned to
+        the canonical specs so later shard_map calls see exact
+        layouts."""
         if self.tier is not None:
             # promote spilled postings pinned to the evicted slot first
             # (see tier.TierManager.promote_retrain_pinned); the retrain
@@ -602,7 +607,9 @@ class ShardedUBISDriver:
             self.stats["tier_promoted"] += n
         evict = (int(self.state.pq_active) + 1) % self.cfg.pq_versions
         self._pq_key, k = jax.random.split(self._pq_key)
-        st = pq.retrain_round(self.state, self.cfg, k)
+        if self._retrain_fn is None:
+            self._retrain_fn = make_sharded_retrain(self.cfg, self.mesh)
+        st = self._retrain_fn(self.state, k)
         self.state = jax.device_put(st, self._shardings)
         self.stats["pq_retrains"] += 1
         self.stats["pq_generation"] = int(

@@ -227,8 +227,27 @@ class MultiProcessBackend(ClusterBackend):
         self._queues[worker] = q
 
     def start(self) -> None:
+        self._check_host(self._env())
         for w in range(self.n_workers):
             self._spawn(w)
+
+    @staticmethod
+    def _check_host(env: dict) -> None:
+        """Refuse a TPU host: every worker inherits this environment and
+        would reach for the same chips, which this process already holds
+        once it has touched JAX — the workers would fail or hang.
+        Workers pinned off the chip (``JAX_PLATFORMS`` without ``tpu``)
+        are fine."""
+        platforms = env.get("JAX_PLATFORMS", "")
+        if platforms and "tpu" not in platforms.split(","):
+            return
+        import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "MultiProcessBackend cannot start workers on a TPU host: "
+                "each worker process would claim the same chips this "
+                "process holds. Use the in-process LocalBackend, or pin "
+                "the workers to the CPU with JAX_PLATFORMS=cpu.")
 
     def stop(self) -> None:
         for w, proc in enumerate(self._procs):

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops
+from ..kernels import ops, ref
 from ..kernels.posting_scan import BIG
 from . import version_manager as vm
 from .types import (KIND_COMPACT, KIND_MERGE, KIND_NONE, KIND_SPLIT, NO_ID,
@@ -104,7 +104,7 @@ def _median_bisect(tile, mask):
     n = jnp.maximum(jnp.sum(mask), 1)
     mean = jnp.sum(jnp.where(mask[:, None], x, 0), 0) / n
     var = jnp.sum(jnp.where(mask[:, None], (x - mean) ** 2, 0), 0)
-    axis = jnp.argmax(var)
+    axis = ref.exact_argmax(var)
     vals = jnp.where(mask, x[:, axis], BIG)
     order = jnp.argsort(vals)            # valid rows first, ascending
     rank = jnp.zeros((C,), jnp.int32).at[order].set(
@@ -132,7 +132,7 @@ def _two_means(tile, mask, iters: int, init: str = "median"):
         first = jnp.argmax(mask)
         c0 = x[first]
         d0 = jnp.where(mask, jnp.sum((x - c0) ** 2, -1), -BIG)
-        c1 = x[jnp.argmax(d0)]
+        c1 = x[ref.exact_argmax(d0)]
 
     def body(_, carry):
         c0, c1 = carry
@@ -252,10 +252,11 @@ def balance_split(state: IndexState, cfg: UBISConfig, pid):
     other = other.at[pid].set(False)
     sc = ops.centroid_score(tile.astype(jnp.float32), state.centroids, other,
                             backend=cfg.use_pallas)           # (C, M)
-    best_other = jnp.argmin(sc, -1).astype(jnp.int32)
+    best_other = ref.exact_argmin(sc, -1)
     best_d = jnp.min(sc, -1)
     d_big = (jnp.sum(tile.astype(jnp.float32) ** 2, -1)
-             - 2 * tile.astype(jnp.float32) @ c_big
+             - 2 * jnp.matmul(tile.astype(jnp.float32), c_big,
+                              precision=ref.HIGHEST)
              + jnp.sum(c_big ** 2))
     # score convention: sc already excludes ||p||^2, so compare apples:
     d_big_score = d_big - jnp.sum(tile.astype(jnp.float32) ** 2, -1)
@@ -354,7 +355,7 @@ def merge_postings(state: IndexState, cfg: UBISConfig, pid):
     eligible = eligible.at[pid].set(False)
     sc = ops.centroid_score(state.centroids[pid][None], state.centroids,
                             eligible, backend=cfg.use_pallas)[0]
-    partner = jnp.argmin(sc).astype(jnp.int32)
+    partner = ref.exact_argmin(sc)
     has_partner = sc[partner] < BIG / 2
     ver = state.global_version + jnp.uint32(1)
 
@@ -421,10 +422,11 @@ def reassign_check(state: IndexState, cfg: UBISConfig, pid):
     other = other.at[pid].set(False)
     sc = ops.centroid_score(tile, state.centroids, other,
                             backend=cfg.use_pallas)
-    best_other = jnp.argmin(sc, -1).astype(jnp.int32)
+    best_other = ref.exact_argmin(sc, -1)
     best_d = jnp.min(sc, -1)
     own = state.centroids[pid].astype(jnp.float32)
-    d_own = jnp.sum(own * own) - 2 * tile @ own
+    d_own = (jnp.sum(own * own)
+             - 2 * jnp.matmul(tile, own, precision=ref.HIGHEST))
     move = mask & (best_d < d_own)
 
     state, ok, _ = batched_append(state, cfg, tile, tids,
@@ -569,7 +571,7 @@ def background_round(state: IndexState, cfg: UBISConfig, kinds, pids,
                              backend=cfg.use_pallas)            # (B, M)
     psc = jnp.where(state.lengths[None, :] + n_me[:, None] < cfg.l_max,
                     psc, BIG)
-    partner = jnp.argmin(psc, -1).astype(jnp.int32)
+    partner = ref.exact_argmin(psc, -1)
     has_partner = (jnp.min(psc, -1) < BIG / 2) & is_merge
     pkey = jnp.where(has_partner, partner,
                      -2 - jnp.arange(B, dtype=jnp.int32))
@@ -646,10 +648,11 @@ def background_round(state: IndexState, cfg: UBISConfig, kinds, pids,
         sc = ops.centroid_score(tiles.reshape(B * C, d), state.centroids,
                                 normal0 & ~retiring,
                                 backend=cfg.use_pallas)
-        best_other = jnp.argmin(sc, -1).astype(jnp.int32).reshape(B, C)
+        best_other = ref.exact_argmin(sc, -1).reshape(B, C)
         best_d = jnp.min(sc, -1).reshape(B, C)
         d_big_score = (jnp.sum(c_big ** 2, -1)[:, None]
-                       - 2 * jnp.einsum("bcd,bd->bc", tiles, c_big))
+                       - 2 * jnp.einsum("bcd,bd->bc", tiles, c_big,
+                                        precision=ref.HIGHEST))
         nearer = best_d < d_big_score
         move_out = (imbalanced[:, None] & small_mask & nearer
                     & split_exec[:, None])
@@ -868,12 +871,12 @@ def background_round(state: IndexState, cfg: UBISConfig, kinds, pids,
                 backend=cfg.use_pallas)
             own = jnp.broadcast_to(rs[:, None], (3 * B, C)).reshape(-1)
             sc2 = sc2.at[jnp.arange(3 * B * C), own].set(BIG)
-            r_best = jnp.argmin(sc2, -1).astype(jnp.int32)
+            r_best = ref.exact_argmin(sc2, -1)
             r_bd = jnp.min(sc2, -1)
             own_c = state.centroids[rs].astype(jnp.float32)
             d_own = (jnp.sum(own_c ** 2, -1)[:, None]
-                     - 2 * jnp.einsum("bcd,bd->bc", r_tiles,
-                                      own_c)).reshape(-1)
+                     - 2 * jnp.einsum("bcd,bd->bc", r_tiles, own_c,
+                                      precision=ref.HIGHEST)).reshape(-1)
             mv = r_mask.reshape(-1) & (r_bd < d_own)
             state, mv_ok, _ = batched_append(
                 state, cfg, r_tiles.reshape(-1, d), r_ids.reshape(-1),

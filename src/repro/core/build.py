@@ -19,8 +19,9 @@ from .types import IndexState, UBISConfig, empty_state
 from .update import alloc_postings, dataclasses_replace
 
 
-@functools.partial(jax.jit, static_argnames=("k", "iters"))
-def kmeans(points: jax.Array, k: int, iters: int, key: jax.Array):
+@functools.partial(jax.jit, static_argnames=("k", "iters", "backend"))
+def kmeans(points: jax.Array, k: int, iters: int, key: jax.Array,
+           backend: str = "auto"):
     """Plain Lloyd k-means; empty clusters keep their previous centroid
     (they become zero-length postings and the merge path sweeps them)."""
     n, d = points.shape
@@ -28,7 +29,7 @@ def kmeans(points: jax.Array, k: int, iters: int, key: jax.Array):
     cents = points[idx].astype(jnp.float32)
 
     def body(_, cents):
-        assign, _ = ops.kmeans_assign(points, cents, backend="ref")
+        assign, _ = ops.kmeans_assign(points, cents, backend=backend)
         sums = jnp.zeros((k, d), jnp.float32).at[assign].add(points)
         counts = jnp.zeros((k,), jnp.float32).at[assign].add(1.0)
         new = sums / jnp.maximum(counts, 1.0)[:, None]
@@ -43,7 +44,7 @@ def seed_postings(state: IndexState, cfg: UBISConfig, centroids_k, k: int):
     centroid neighbourhood graph (top-G mutual neighbours)."""
     state, pids = alloc_postings(state, cfg, k, centroids_k,
                                  jnp.uint32(0))
-    sc = ops.centroid_score(centroids_k, centroids_k, backend="ref")
+    sc = ops.centroid_score(centroids_k, centroids_k, backend=cfg.use_pallas)
     sc = sc + jnp.eye(k) * 1e30  # exclude self
     g = min(cfg.graph_degree, max(k - 1, 1))
     _, nn = jax.lax.top_k(-sc, g)
@@ -66,7 +67,7 @@ def initial_state(cfg: UBISConfig, seed_vectors, *, key=None,
     k0 = max(1, min(int(round(n / (target_fill * cfg.l_max))),
                     cfg.max_postings // 4))
     sample = jnp.asarray(seed_vectors[:sample_cap], jnp.float32)
-    cents = kmeans(sample, k0, cfg.kmeans_iters, key)
+    cents = kmeans(sample, k0, cfg.kmeans_iters, key, backend=cfg.use_pallas)
     state = empty_state(cfg)
     state, _ = seed_postings(state, cfg, cents, k0)
     if cfg.use_pq:

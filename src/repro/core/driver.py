@@ -483,14 +483,16 @@ class UBISDriver:
             return 0
         split_like = [p for k_, p in jobs if k_ in ("split", "compact")]
         merge_like = [p for k_, p in jobs if k_ == "merge"]
+        # pad to the batch width (-1 lanes are dropped): one compiled
+        # program per status, not one per batch length
+        padded = lambda p: jnp.asarray(p + [-1] * (self.bg_ops - len(p)),
+                                       jnp.int32)
         if split_like:
             self.state = update.mark_status(
-                self.state, jnp.asarray(split_like, jnp.int32),
-                STATUS_SPLITTING)
+                self.state, padded(split_like), STATUS_SPLITTING)
         if merge_like:
             self.state = update.mark_status(
-                self.state, jnp.asarray(merge_like, jnp.int32),
-                STATUS_MERGING)
+                self.state, padded(merge_like), STATUS_MERGING)
         self._marked.extend(jobs)
         self._marked_set.update(p for _, p in jobs)
         self.obs.emit(

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..api.types import SearchResult, TickReport, UpdateResult
+from ..kernels import ref
 
 BIG = 1e30
 
@@ -85,7 +86,7 @@ def beam_search(state: GraphState, cfg: GraphConfig, queries,
             cand, dist, expanded = carry
             # best unexpanded candidate
             score = jnp.where(expanded | (cand < 0), BIG, dist)
-            i = jnp.argmin(score)
+            i = ref.exact_argmin(score)
             has = score[i] < BIG / 2
             expanded = expanded.at[i].set(True)
             node = jnp.maximum(cand[i], 0)

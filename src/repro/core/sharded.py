@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..distributed.sharding import axis_size as _axis_size, shard_map
 from ..kernels import ops, ref
 from ..kernels.posting_scan import BIG
 from . import balance, version_manager as vm
@@ -152,7 +151,7 @@ def make_sharded_search(cfg: UBISConfig, mesh: Mesh, k: int,
     probe_cap = getattr(cfg, "shard_probe_cap", 0)
 
     def local(state: IndexState, queries):
-        n_shard = _axis_size("model")
+        n_shard = jax.lax.axis_size("model")
         my = jax.lax.axis_index("model")
         M_local = state.centroids.shape[0]
         Q = queries.shape[0]
@@ -229,7 +228,8 @@ def make_sharded_search(cfg: UBISConfig, mesh: Mesh, k: int,
         return found, sf
 
     in_specs = (st_specs, qspec)
-    fn = shard_map(local, mesh, in_specs, (qspec, qspec))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=(qspec, qspec), check_vma=False)
     return jax.jit(fn)
 
 
@@ -274,10 +274,10 @@ def make_sharded_insert(cfg: UBISConfig, mesh: Mesh,
         # spilled postings cannot take appends (float tile host-resident)
         insertable = (state.allocated & (status == 0)
                       & ~state.tier_spilled)
-        sc = ref.centroid_score(vecs.astype(jnp.float32), state.centroids)
-        sc = jnp.where(insertable[None, :], sc, BIG)
+        sc = ops.centroid_score(vecs.astype(jnp.float32), state.centroids,
+                                insertable, backend=cfg.use_pallas)
         best_local = jnp.min(sc, axis=1)
-        best_pid = jnp.argmin(sc, axis=1).astype(jnp.int32)
+        best_pid = ref.exact_argmin(sc, axis=1)
         # global owner = argmin over shards
         all_best = jax.lax.all_gather(best_local, "model", axis=0)  # (S, J)
         if route_alpha:
@@ -296,7 +296,7 @@ def make_sharded_insert(cfg: UBISConfig, mesh: Mesh,
                 finite,
                 all_best + route_alpha * sat_all[:, None] * rng_j[None, :],
                 all_best)
-        owner = jnp.argmin(all_best, axis=0).astype(jnp.int32)
+        owner = ref.exact_argmin(all_best, axis=0)
         mine = valid & (owner == my) & (best_local < BIG / 2)
         # routed GLOBAL pid per job (one-hot psum: exactly one shard is
         # the argmin owner) — the cache-target hint for parked jobs
@@ -325,8 +325,9 @@ def make_sharded_insert(cfg: UBISConfig, mesh: Mesh,
             global_version=state.global_version + jnp.uint32(1))
         return state, valid & any_won, routed
 
-    fn = shard_map(local, mesh, (st_specs, jspec, jspec, jspec),
-                   (st_specs, P(), P()))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(st_specs, jspec, jspec, jspec),
+                       out_specs=(st_specs, P(), P()), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -361,7 +362,8 @@ def make_sharded_delete(cfg: UBISConfig, mesh: Mesh):
                                        in_cache, base=base)
         return state, done
 
-    fn = shard_map(local, mesh, (st_specs, jspec, jspec), (st_specs, P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(st_specs, jspec, jspec),
+                       out_specs=(st_specs, P()), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -468,8 +470,9 @@ def make_sharded_background(cfg: UBISConfig, mesh: Mesh,
         pressure = balance.shard_pressure(state, cfg, base_pid=base_pid)
         return state, executed, reclaimed, pressure[None]
 
-    fn = shard_map(local, mesh, (st_specs, P()),
-                   (st_specs, P(), P(), P("model")))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(st_specs, P()),
+                       out_specs=(st_specs, P(), P(), P("model")),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -524,7 +527,7 @@ def make_sharded_migrate(cfg: UBISConfig, mesh: Mesh, jobs: int = 8):
 
     def local(state: IndexState, src_pids, dst_shards, valid):
         my = jax.lax.axis_index("model").astype(jnp.int32)
-        n_shard = _axis_size("model")
+        n_shard = jax.lax.axis_size("model")
         M_local = state.lengths.shape[0]
         base_pid = my * M_local
         ver = state.global_version + jnp.uint32(1)
@@ -645,8 +648,8 @@ def make_sharded_migrate(cfg: UBISConfig, mesh: Mesh, jobs: int = 8):
             global_version=ver)
         return state, migrated, new_global
 
-    fn = shard_map(local, mesh, (st_specs, P(), P(), P()),
-                   (st_specs, P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(st_specs, P(), P(), P()),
+                       out_specs=(st_specs, P(), P()), check_vma=False)
     jfn = jax.jit(fn, donate_argnums=(0,))
 
     def checked(state, src_pids, dst_shards, valid):
@@ -658,6 +661,35 @@ def make_sharded_migrate(cfg: UBISConfig, mesh: Mesh, jobs: int = 8):
         return jfn(state, src_pids, dst_shards, valid)
 
     return checked
+
+
+def make_sharded_retrain(cfg: UBISConfig, mesh: Mesh):
+    """(state, key) -> state: ``pq.retrain_round`` for a model-sharded
+    state.  Drawing the sample and installing the codebooks (gathers,
+    XLA encode) are plain jit programs that GSPMD partitions over the
+    state's shardings; the codebook training runs replicated under
+    ``shard_map``, since a Pallas kernel (``kmeans_assign``) cannot be
+    partitioned automatically.  Same steps, same result as the
+    single-device round."""
+    from ..quant import pq
+
+    def train(sample, smask, codebooks, active):
+        return pq.train_codebooks(sample, smask, codebooks[active],
+                                  cfg.kmeans_iters, backend=cfg.use_pallas)
+
+    sample_fn = jax.jit(pq.retrain_sample, static_argnums=1)
+    train_fn = jax.jit(jax.shard_map(
+        train, mesh=mesh, in_specs=(P(), P(), P(), P()), out_specs=P(),
+        check_vma=False))
+    install_fn = jax.jit(pq.install_codebooks, static_argnums=1)
+
+    def run(state, key):
+        sample, smask = sample_fn(state, cfg, key)
+        new_cb = train_fn(sample, smask, state.pq_codebooks,
+                          state.pq_active)
+        return install_fn(state, cfg, new_cb)
+
+    return run
 
 
 def make_sharded_exact(cfg: UBISConfig, mesh: Mesh, k: int):
@@ -676,7 +708,7 @@ def make_sharded_exact(cfg: UBISConfig, mesh: Mesh, k: int):
     st_specs = index_specs(cfg)
 
     def local(state: IndexState, queries):
-        n_shard = _axis_size("model")
+        n_shard = jax.lax.axis_size("model")
         my = jax.lax.axis_index("model")
         queries = queries.astype(jnp.float32)
         vis = vm.visible(state.rec_meta, state.allocated,
@@ -684,12 +716,13 @@ def make_sharded_exact(cfg: UBISConfig, mesh: Mesh, k: int):
         # spilled postings excluded (device tiles zeroed) — the tiered
         # driver merges a host-pool scan on top, same as single-device
         valid = state.slot_valid & (vis & ~state.tier_spilled)[:, None]
-        s = ref.posting_scan(queries, state.vectors, valid)  # (Q, M_local*C)
+        s = ops.posting_scan(queries, state.vectors, valid,
+                             backend=cfg.use_pallas)          # (Q, M_local*C)
         ids_row = state.ids.reshape(-1)
         # cache slice: the same ownership split as the sharded search
         cvs, cval_own, cid = _owned_cache_slice(state, my, n_shard)
-        cs = ref.centroid_score(queries, cvs)
-        cs = jnp.where(cval_own[None, :], cs, BIG)
+        cs = ops.centroid_score(queries, cvs, cval_own,
+                                backend=cfg.use_pallas)
         scores = jnp.concatenate([s, cs], axis=1)
         flat = jnp.concatenate([ids_row, cid])
         ids2d = jnp.broadcast_to(flat[None, :],
@@ -701,5 +734,6 @@ def make_sharded_exact(cfg: UBISConfig, mesh: Mesh, k: int):
         sf, idf = _local_topk(s_all, i_all, k)
         return jnp.where(sf < BIG / 2, idf, -1), sf
 
-    fn = shard_map(local, mesh, (st_specs, P()), (P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(st_specs, P()),
+                       out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)

@@ -55,7 +55,10 @@ class UBISConfig:
     succ_chase_depth: int = 4         # bounded DELETED pointer chasing
     dtype: Any = jnp.float32          # vector storage dtype
     mode: str = "ubis"                # "ubis" | "spfresh" (baseline semantics)
-    use_pallas: str = "auto"          # "auto" | "on" | "off"  (kernel backend)
+    # kernel backend: "on" = Pallas kernels (compiled on a TPU, run in
+    # interpret mode elsewhere), "off" = the jnp reference twins,
+    # "auto" = kernels on a TPU backend, reference elsewhere
+    use_pallas: str = "auto"
     # distributed search: cap owned probes scanned per shard (0 = nprobe);
     # ~4x phase-2 work reduction on a 16-way pod (EXPERIMENTS.md §Perf)
     shard_probe_cap: int = 0
@@ -79,6 +82,10 @@ class UBISConfig:
     tier_promote_heat: int = 8        # heat >= this -> promote (search-heat)
 
     def __post_init__(self):
+        from ..kernels.ops import BACKENDS
+        if self.use_pallas not in BACKENDS:
+            raise ValueError(f"use_pallas={self.use_pallas!r}; choose from "
+                             f"{BACKENDS}")
         assert self.max_postings < NO_SUCC, "successor ids are 16-bit"
         assert self.capacity >= self.l_max, "tile must hold an over-full posting"
         assert self.capacity <= 2 * self.l_max, \

@@ -24,7 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops
+from ..kernels import ops, ref
 from . import version_manager as vm
 from .types import (NO_ID, NO_SUCC, STATUS_DELETED, STATUS_MERGING,
                     STATUS_NORMAL, STATUS_SPLITTING, IndexState, RoundResult,
@@ -310,7 +310,7 @@ def insert_round(state: IndexState, cfg: UBISConfig, vecs, ids, valid,
 
     scores = ops.centroid_score(vecs, state.centroids, insertable,
                                 backend=cfg.use_pallas)
-    located = jnp.argmin(scores, axis=-1).astype(jnp.int32)
+    located = ref.exact_argmin(scores, axis=-1)
     pid = jnp.where(chased_ok, chased, located)
 
     st = status[pid]

@@ -21,34 +21,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (without replication checking).
-
-    ``jax.shard_map`` only exists on newer jax (and its no-check kwarg
-    was renamed ``check_rep`` -> ``check_vma`` along the way); older
-    releases ship it under ``jax.experimental.shard_map``.  Pinning
-    either spelling breaks one side of the CI matrix, so dispatch here.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size(name: str):
-    """``jax.lax.axis_size`` portability shim (absent before jax 0.5).
-    Must be called inside a shard_map/pmap context."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def make_rules(mesh: Mesh, kind: str = "train",
                long_context: bool = False) -> Dict[str, Any]:
     axes = mesh.axis_names

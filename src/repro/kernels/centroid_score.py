@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .posting_scan import BIG
+from .posting_scan import BIG, dots_qt, sq_norms_row
 
 DEFAULT_BQ = 128
 DEFAULT_BM = 512
@@ -25,12 +25,10 @@ DEFAULT_BM = 512
 def _kernel(q_ref, c_ref, vis_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)          # (BQ, d)
     c = c_ref[...].astype(jnp.float32)          # (BM, d)
-    vis = vis_ref[...]                          # (1, BM)
-    cn = jnp.sum(c * c, axis=-1)                # fused norm epilogue
-    dots = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    out_ref[...] = jnp.where(vis, cn[None, :] - 2.0 * dots, BIG)
+    vis = vis_ref[...] != 0                     # (1, BM)
+    # fused norm epilogue
+    out_ref[...] = jnp.where(vis, sq_norms_row(c) - 2.0 * dots_qt(q, c),
+                             BIG)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bm", "interpret"))

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .posting_scan import BIG
+from .posting_scan import BIG, dots_qt, sq_norms_row
 
 DEFAULT_BQ = 128
 DEFAULT_BM = 512
@@ -39,28 +39,41 @@ def merge_topk(run_s, run_i, tile_s, tile_i, k: int):
     run_s/run_i: (rows, k) current best scores (ascending) and indices;
     tile_s/tile_i: (rows, n) this tile's candidate scores and indices.
     Returns the new (rows, k) pair, ascending by (score, arrival).
-
-    Selection is k rounds of (min, argmin, mask) over the concatenated
-    candidate row — VPU-only primitives, no sort/top_k lowering needed.
-    ``argmin`` returns the lowest position on ties, and running entries
-    (earlier candidates) sit before tile entries in the concatenation,
-    so the global tie order is lowest-candidate-index-first, matching
-    ``lax.top_k`` on the full score row.  Empty running slots hold
-    +inf (> BIG), so masked-but-real candidates always win over them.
+    Running entries (earlier candidates) sit before tile entries in the
+    concatenation, so the global tie order is lowest-candidate-index-
+    first, matching ``lax.top_k`` on the full score row.  Empty running
+    slots hold +inf (> BIG), so masked-but-real candidates always win
+    over them.
     """
     s = jnp.concatenate([run_s, tile_s], axis=1)        # (rows, k + n)
     idx = jnp.concatenate([run_i, tile_i], axis=1)
+    return topk_rows(s, idx, k)
+
+
+def topk_rows(s, idx, k: int):
+    """The k smallest entries of each row of ``s`` (with their ``idx``),
+    ascending; ties go to the lowest position.
+
+    Selection is k rounds of (min, first-position-of-min, mask) — VPU
+    and cross-lane reductions only, no sort/top_k lowering needed.  Each
+    round's pick lands in lane ``t`` of the (rows, k) result, so no
+    vector ever changes layout.
+    """
     rows, n_all = s.shape
     pos = jax.lax.broadcasted_iota(jnp.int32, (rows, n_all), 1)
-    out_s, out_i = [], []
-    for _ in range(k):                                  # k static, small
-        best = jnp.min(s, axis=1)
-        arg = jnp.argmin(s, axis=1).astype(jnp.int32)
-        hit = pos == arg[:, None]
-        out_s.append(best)
-        out_i.append(jnp.sum(jnp.where(hit, idx, 0), axis=1))
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (rows, k), 1)
+    out_s = jnp.zeros((rows, k), s.dtype)
+    out_i = jnp.zeros((rows, k), idx.dtype)
+    for t in range(k):                                  # k static, small
+        best = jnp.min(s, axis=1, keepdims=True)        # (rows, 1)
+        arg = jnp.min(jnp.where(s == best, pos, n_all), axis=1,
+                      keepdims=True)
+        hit = pos == arg
+        pick = jnp.sum(jnp.where(hit, idx, 0), axis=1, keepdims=True)
+        out_s = jnp.where(out_lane == t, best, out_s)
+        out_i = jnp.where(out_lane == t, pick, out_i)
         s = jnp.where(hit, jnp.inf, s)                  # retire the pick
-    return jnp.stack(out_s, axis=1), jnp.stack(out_i, axis=1)
+    return out_s, out_i
 
 
 def _kernel(q_ref, c_ref, vis_ref, s_ref, i_ref, *, k):
@@ -75,12 +88,9 @@ def _kernel(q_ref, c_ref, vis_ref, s_ref, i_ref, *, k):
 
     q = q_ref[...].astype(jnp.float32)                  # (bq, d)
     c = c_ref[...].astype(jnp.float32)                  # (bm, d)
-    vis = vis_ref[...]                                  # (1, bm)
-    cn = jnp.sum(c * c, axis=-1)                        # fused norm epilogue
-    dots = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    score = jnp.where(vis, cn[None, :] - 2.0 * dots, BIG)
+    vis = vis_ref[...] != 0                             # (1, bm)
+    # fused norm epilogue
+    score = jnp.where(vis, sq_norms_row(c) - 2.0 * dots_qt(q, c), BIG)
     bq, bm = score.shape
     tile_i = (jax.lax.broadcasted_iota(jnp.int32, (bq, bm), 1)
               + j * bm)

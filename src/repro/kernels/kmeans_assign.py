@@ -10,7 +10,7 @@ the idiomatic accumulator pattern.
 
     points    : (N, d)
     centroids : (K, d)
-    ->  assign (N, 1) int32, best (N, 1) f32
+    ->  assign (N,) int32, best (N,) f32  (lane-dense (1, N) carries)
 """
 from __future__ import annotations
 
@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .posting_scan import BIG
+from .posting_scan import BIG, dots_qt, lane_row, sq_norms_row
 
 DEFAULT_BN = 256
 DEFAULT_BK = 128
@@ -36,28 +36,31 @@ def _kernel(p_ref, c_ref, assign_ref, best_ref):
 
     p = p_ref[...].astype(jnp.float32)          # (BN, d)
     c = c_ref[...].astype(jnp.float32)          # (BK, d)
-    cn = jnp.sum(c * c, axis=-1)
-    dots = jax.lax.dot_general(
-        p, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    score = cn[None, :] - 2.0 * dots            # (BN, BK)
-    blk_best = jnp.min(score, axis=-1)
-    blk_arg = jnp.argmin(score, axis=-1).astype(jnp.int32)
-    blk_arg = blk_arg + j * score.shape[1]
-    prev_best = best_ref[...][:, 0]
-    prev_arg = assign_ref[...][:, 0]
+    score = sq_norms_row(c) - 2.0 * dots_qt(p, c)   # (BN, BK)
+    bk = score.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+    blk_best = jnp.min(score, axis=-1, keepdims=True)          # (BN, 1)
+    blk_arg = jnp.min(jnp.where(score == blk_best, lane, bk), axis=-1,
+                      keepdims=True) + j * bk
+    # lane-dense carry: the per-point pair lives in (1, BN) rows
+    blk_best, blk_arg = lane_row(blk_best), lane_row(blk_arg)
+    prev_best = best_ref[...]
     take = blk_best < prev_best
-    best_ref[...] = jnp.where(take, blk_best, prev_best)[:, None]
-    assign_ref[...] = jnp.where(take, blk_arg, prev_arg)[:, None]
+    best_ref[...] = jnp.where(take, blk_best, prev_best)
+    assign_ref[...] = jnp.where(take, blk_arg, assign_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
 def kmeans_assign(points: jax.Array, centroids: jax.Array,
                   *, bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                   interpret: bool = False):
+    """Padded-shape Pallas entry: N % bn == 0 (bn a multiple of 128),
+    K % bk == 0, d % 128 == 0, guaranteed by the ops.py wrapper.
+    Returns (assign (N,) int32, best (N,) f32)."""
     N, d = points.shape
     K = centroids.shape[0]
     grid = (N // bn, K // bk)
+    row = pl.BlockSpec((1, bn), lambda i, j: (0, i))
     assign, best = pl.pallas_call(
         _kernel,
         grid=grid,
@@ -65,14 +68,11 @@ def kmeans_assign(points: jax.Array, centroids: jax.Array,
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((N, 1), jnp.int32),
-            jax.ShapeDtypeStruct((N, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, N), jnp.int32),
+            jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         interpret=interpret,
     )(points, centroids)
-    return assign[:, 0], best[:, 0]
+    return assign[0], best[0]

@@ -1,10 +1,9 @@
 """Public kernel entry points: padding, backend dispatch, jit wrappers.
 
-Every op has three backends:
-  * ``ref``     — pure-jnp oracle (``ref.py``), always correct, XLA-fused;
-  * ``pallas``  — the TPU kernel (compiled on TPU, interpret=True on CPU);
-  * ``auto``    — pallas on TPU backends, ref elsewhere (the multi-pod
-                  dry-run therefore lowers the XLA path, per DESIGN.md §5).
+Every op has three backends, spelled as ``UBISConfig.use_pallas``:
+  * ``off``     — the pure-jnp twin (``ref.py``), always correct, XLA-fused;
+  * ``on``      — the Pallas kernel (compiled on TPU, interpret=True on CPU);
+  * ``auto``    — the kernel on TPU backends, the twin elsewhere.
 
 Callers pass logical shapes; wrappers pad to hardware-aligned tiles
 (lane dim 128, sublane 8) and slice results back.
@@ -29,10 +28,16 @@ from .flash_attention import flash_attention as _fa_pallas
 _PAD_CENTROID = 1e6  # padded rows score ~1e14 >> any real score
 
 
+BACKENDS = ("auto", "on", "off")
+
+
 def _use_pallas(backend: str) -> bool:
+    if backend not in BACKENDS:
+        raise ValueError(f"kernel backend {backend!r} is not one of "
+                         f"{BACKENDS}")
     if backend == "auto":
         return jax.default_backend() == "tpu"
-    return backend == "pallas"
+    return backend == "on"
 
 
 def _interpret() -> bool:
@@ -166,7 +171,7 @@ def centroid_score(q: jax.Array, c: jax.Array,
     Qp, Mp, dp = _ceil(Q, bq), _ceil(M, bm), _ceil(d, 128)
     qp = _pad2(q, Qp, dp)
     cp = _pad2(c, Mp, dp, value=_PAD_CENTROID)
-    vp = jnp.pad(vis, (0, Mp - M))[None, :]
+    vp = jnp.pad(vis, (0, Mp - M)).astype(jnp.int32)[None, :]
     out = _cs_pallas(qp, cp, vp, bq=bq, bm=bm, interpret=_interpret())
     return out[:Q, :M]
 
@@ -192,8 +197,9 @@ def centroid_topk(q: jax.Array, c: jax.Array,
     Qp, Mp, dp = _ceil(Q, bq), _ceil(M, bm), _ceil(d, 128)
     qp = _pad2(q, Qp, dp)
     cp = _pad2(c, Mp, dp, value=_PAD_CENTROID)
-    vp = jnp.pad(vis, (0, Mp - M))[None, :]   # padded rows masked -> BIG;
-    # k <= M real candidates always outrank the padded tail on ties
+    vp = jnp.pad(vis, (0, Mp - M)).astype(jnp.int32)[None, :]
+    # padded rows masked -> BIG; k <= M real candidates always
+    # outrank the padded tail on ties
     s, i = _ct_pallas(qp, cp, vp, k=k, bq=bq, bm=bm,
                       interpret=_interpret())
     return s[:Q], i[:Q]
@@ -213,7 +219,7 @@ def posting_scan(q: jax.Array, tiles: jax.Array, valid: jax.Array,
     Qp, Vp, dp = _ceil(Q, bq), _ceil(V, bv), _ceil(d, 128)
     qp = _pad2(q, Qp, dp)
     vp = _pad2(tiles.reshape(V, d), Vp, dp)
-    mp = jnp.pad(valid.reshape(V), (0, Vp - V))[None, :]
+    mp = jnp.pad(valid.reshape(V), (0, Vp - V)).astype(jnp.int32)[None, :]
     out = _ps_pallas(qp, vp, mp, bq=bq, bv=bv, interpret=_interpret())
     return out[:Q, :V]
 
@@ -227,7 +233,7 @@ def kmeans_assign(points: jax.Array, centroids: jax.Array,
     if not _use_pallas(backend):
         a, b = ref.kmeans_assign(points, centroids, mask)
         return a, jnp.where(jnp.isfinite(b), b, BIG)
-    bn = 256 if N >= 256 else _ceil(N, 8)
+    bn = 256 if N >= 256 else _ceil(N, 128)  # lane-dense output rows
     bk = 128  # lane-width tile; K pads up to a multiple (sentinel rows)
     Np, Kp, dp = _ceil(N, bn), _ceil(K, bk), _ceil(d, 128)
     pp = _pad2(points, Np, dp)
@@ -267,38 +273,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return out[:, :, Lqp - Lq:, :D]
 
 
-def pq_scan_gather(luts: jax.Array, codes: jax.Array,
-                   posting_slot: jax.Array, slot_valid: jax.Array,
-                   vis: jax.Array, probe: jax.Array,
-                   *, backend: str = "auto"):
-    """ADC scan of probed PQ-code tiles (quant plane, DESIGN: two-stage
-    search).  luts: (Q, V, m, ksub); codes: (M, m, C) uint8;
-    posting_slot: (M,) int32; probe: (Q, P) -> (Q, P, C) scores, BIG at
-    invalid slots / invisible postings.
-
-    Alignment-free: C and ksub zero-pad up to the TPU storage layout
-    (128 lanes) here — padded lut columns are unreachable (codes < the
-    logical ksub) and padded code lanes are sliced back off, so any
-    C/ksub serves the fused kernel.  Aligned storage makes both pads
-    no-ops; misaligned storage pays one codes-layout copy per call.
-    """
-    from .pq_scan import pq_scan_gather as _pq_pallas
-    V = luts.shape[1]
-    C = codes.shape[2]
-    ksub = luts.shape[3]
-    slot = jnp.clip(posting_slot.astype(jnp.int32), 0, V - 1)
-    if not _use_pallas(backend):
-        raw = ref.pq_scan_gather(luts, codes, slot, probe)
-    else:
-        Cp, ksubp = _ceil(C, 128), _ceil(ksub, 128)
-        lp = jnp.pad(luts, ((0, 0), (0, 0), (0, 0), (0, ksubp - ksub)))
-        cdp = jnp.pad(codes, ((0, 0), (0, 0), (0, Cp - C)))
-        raw = _pq_pallas(lp, cdp, slot, probe.astype(jnp.int32),
-                         interpret=_interpret())[:, :, :C]
-    ok = slot_valid[probe] & vis[probe][..., None]
-    return jnp.where(ok, raw, BIG)
-
-
 def pq_scan_topk(luts: jax.Array, codes: jax.Array,
                  posting_slot: jax.Array, slot_valid: jax.Array,
                  vis: jax.Array, probe: jax.Array, *, k: int,
@@ -306,15 +280,18 @@ def pq_scan_topk(luts: jax.Array, codes: jax.Array,
                  backend: str = "auto"):
     """Fused ADC scan + top-k (quant-plane phase 2).
 
-    Same inputs as :func:`pq_scan_gather` plus ``k`` and an optional
-    per-(query, probe) mask ``qp_ok`` (the sharded plane's ownership
-    mask); returns (scores (Q, k) ascending, cand (Q, k) int32 flat
-    slot index ``probe*C + c``) with BIG at masked candidates.  On the
-    pallas path the (Q, P, C) score tensor is never materialized —
-    selection runs on-chip against the streamed code tiles.
-    Alignment-free (same padding as ``pq_scan_gather``; padded lanes are
-    masked to +inf in-kernel so the BIG-tie order stays bit-identical to
-    the ref twin)."""
+    luts: (Q, V, m, ksub); codes: (M, m, C) uint8; posting_slot: (M,)
+    int32; slot_valid: (M, C); vis: (M,); probe: (Q, P); ``k`` and an
+    optional per-(query, probe) mask ``qp_ok`` (the sharded plane's
+    ownership mask).  Returns (scores (Q, k) ascending, cand (Q, k)
+    int32 flat slot index ``probe*C + c``) with BIG at masked
+    candidates.  On the pallas path the (Q, P, C) score tensor is never
+    materialized — selection runs on-chip against the streamed code
+    tiles.  Alignment-free: ksub zero-pads to the 128-lane tile and C to
+    the 8-row tile here — padded lut columns are unreachable (codes <
+    the logical ksub) and padded code lanes are masked to +inf
+    in-kernel, so the BIG-tie order stays bit-identical to the ref
+    twin."""
     from .pq_scan import pq_scan_topk as _pqt_pallas
     Q, V, m, ksub = luts.shape
     C = codes.shape[2]
@@ -327,37 +304,13 @@ def pq_scan_topk(luts: jax.Array, codes: jax.Array,
     qp_ok = qp_ok.astype(jnp.int32)
     if not _use_pallas(backend):
         return ref.pq_scan_topk(luts, codes, slot, valid, qp_ok, probe, k)
-    Cp, ksubp = _ceil(C, 128), _ceil(ksub, 128)
+    Cp, ksubp = _ceil(C, 8), _ceil(ksub, 128)
     lp = jnp.pad(luts, ((0, 0), (0, 0), (0, 0), (0, ksubp - ksub)))
     cdp = jnp.pad(codes, ((0, 0), (0, 0), (0, Cp - C)))
-    vp = jnp.pad(valid, ((0, 0), (0, Cp - C)))    # pad lanes False
+    vp = jnp.pad(valid, ((0, 0), (0, Cp - C))).astype(jnp.int32)  # pad 0
     return _pqt_pallas(lp, cdp, slot, vp, qp_ok,
                        probe.astype(jnp.int32), k=k, c=C,
                        interpret=_interpret())
-
-
-def posting_scan_gather(q: jax.Array, vectors: jax.Array,
-                        slot_valid: jax.Array, vis: jax.Array,
-                        probe: jax.Array, *, backend: str = "auto"):
-    """Search phase 2 with in-kernel HBM gather (DESIGN.md §5).
-
-    Alignment-free: d and C zero-pad up to the TPU storage layout here
-    (zero-padding d is fp-exact; padded C lanes slice back off), so any
-    real-world dim serves the fused kernel.  Aligned storage makes the
-    pads no-ops; misaligned storage pays one pool-layout copy per call.
-    """
-    from .posting_scan import posting_scan_gather as _psg_pallas
-    Q, d = q.shape
-    M, C, _ = vectors.shape
-    if not _use_pallas(backend):
-        return ref.posting_scan_gather(q, vectors, slot_valid, vis, probe)
-    Cp, dp = _ceil(C, 128), _ceil(d, 128)
-    qp = jnp.pad(q, ((0, 0), (0, dp - d)))
-    vecp = jnp.pad(vectors, ((0, 0), (0, Cp - C), (0, dp - d)))
-    raw = _psg_pallas(qp, vecp, probe.astype(jnp.int32),
-                      interpret=_interpret())[:, :, :C]
-    ok = slot_valid[probe] & vis[probe][..., None]
-    return jnp.where(ok, raw, BIG)
 
 
 def posting_scan_topk(q: jax.Array, vectors: jax.Array,
@@ -367,12 +320,14 @@ def posting_scan_topk(q: jax.Array, vectors: jax.Array,
                       backend: str = "auto"):
     """Fused float phase 2: probe scan + top-k in one kernel.
 
-    Same inputs as :func:`posting_scan_gather` plus ``k`` and an
-    optional per-(query, probe) mask; returns (scores (Q, k) ascending,
+    q: (Q, d); vectors: (M, C, d); slot_valid: (M, C); vis: (M,);
+    probe: (Q, P); ``k`` and an optional per-(query, probe) mask.
+    Returns (scores (Q, k) ascending,
     cand (Q, k) int32 flat slot index) — no (Q, P, C) score tensor on
-    the pallas path.  Alignment-free (same padding as
-    ``posting_scan_gather``; padded lanes are masked to +inf in-kernel
-    so the BIG-tie order stays bit-identical to the ref twin)."""
+    the pallas path.  Alignment-free: d zero-pads to the 128-lane tile
+    (fp-exact) and C to the 8-row tile here; padded lanes are masked to
+    +inf in-kernel so the BIG-tie order stays bit-identical to the ref
+    twin."""
     from .posting_scan import posting_scan_topk as _pst_pallas
     Q, d = q.shape
     M, C, _ = vectors.shape
@@ -384,10 +339,10 @@ def posting_scan_topk(q: jax.Array, vectors: jax.Array,
     qp_ok = qp_ok.astype(jnp.int32)
     if not _use_pallas(backend):
         return ref.posting_scan_topk(q, vectors, valid, qp_ok, probe, k)
-    Cp, dp = _ceil(C, 128), _ceil(d, 128)
+    Cp, dp = _ceil(C, 8), _ceil(d, 128)
     qp = jnp.pad(q, ((0, 0), (0, dp - d)))
     vecp = jnp.pad(vectors, ((0, 0), (0, Cp - C), (0, dp - d)))
-    vp = jnp.pad(valid, ((0, 0), (0, Cp - C)))    # pad lanes False
+    vp = jnp.pad(valid, ((0, 0), (0, Cp - C))).astype(jnp.int32)  # pad 0
     return _pst_pallas(qp, vecp, vp, qp_ok,
                        probe.astype(jnp.int32), k=k, c=C,
                        interpret=_interpret())

@@ -1,21 +1,19 @@
-"""Pallas TPU kernel: phase-2 masked distance scan over gathered postings.
+"""Pallas TPU kernels: phase-2 masked distance scans over posting tiles.
 
 This is the search hot-spot of a cluster-based index (paper Section V:
-search efficiency): for a batch of queries and the posting tiles chosen
-by phase-1, compute masked L2 scores for every (query, slot) pair.
+search efficiency): for a batch of queries, compute masked L2 scores
+``||v||^2 - 2 q.v`` against posting slots.  Two kernels:
 
-TPU mapping (DESIGN.md Section 5): the query tile (BQ x d) stays resident
-in VMEM while posting-vector tiles (BV x d) stream through; the
-``-2 q.v`` term runs on the MXU (block shapes are 128-aligned), the
-``||v||^2`` epilogue and the tombstone masking run on the VPU.  Scores
-accumulate in fp32 regardless of storage dtype.
+  * ``posting_scan`` — dense scan of a flattened slot range (the exact
+    oracle ``search.brute_force``): the query tile (BQ x d) stays
+    resident in VMEM while slot tiles (BV x d) stream through; the
+    ``-2 q.v`` term runs on the MXU, the norm epilogue and the
+    tombstone masking on the VPU;
+  * ``posting_scan_topk`` — the fused float phase 2 of search: probed
+    posting tiles stream HBM->VMEM by scalar-prefetched probe id and a
+    running top-k is kept on-chip (no (Q, P, C) score tensor in HBM).
 
-Inputs are pre-flattened by ``ops.posting_scan``:
-    q     : (Q, d)      queries
-    v     : (V, d)      V = G * C gathered posting slots
-    valid : (1, V)      live-slot mask (tombstones + tail padding False)
-Output:
-    score : (Q, V) f32  ``||v||^2 - 2 q.v``; +BIG at invalid slots.
+Scores accumulate in fp32 regardless of storage dtype.
 """
 from __future__ import annotations
 
@@ -31,17 +29,38 @@ BIG = 1e30  # stand-in for +inf that survives top-k arithmetic
 DEFAULT_BQ = 128
 DEFAULT_BV = 512
 
+# f32 MXU products at full precision on the chip (no-op in interpret mode)
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def lane_row(col: jax.Array) -> jax.Array:
+    """(n, 1) column -> (1, n) row, exactly (no arithmetic).
+
+    A reduction over lanes leaves its result down the sublanes, while
+    score tiles are laid out along lanes.  Mosaic has no direct
+    sublane->lane relayout for a vector, so broadcast across one lane
+    tile and transpose.  ``n`` is a multiple of 8 (128 for the scans)."""
+    n = col.shape[0]
+    return jnp.transpose(jnp.broadcast_to(col, (n, 128)))[:1, :]
+
+
+def sq_norms_row(x: jax.Array) -> jax.Array:
+    """(n, d) f32 tile -> (1, n) squared row norms."""
+    return lane_row(jnp.sum(x * x, axis=-1, keepdims=True))
+
+
+def dots_qt(q: jax.Array, x: jax.Array) -> jax.Array:
+    """(a, d) x (b, d) -> (a, b) f32 dot products on the MXU."""
+    return jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
+
 
 def _kernel(q_ref, v_ref, valid_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)          # (BQ, d)
     v = v_ref[...].astype(jnp.float32)          # (BV, d)
-    valid = valid_ref[...]                      # (1, BV)
-    vn = jnp.sum(v * v, axis=-1)                # (BV,)
-    # MXU: (BQ, d) @ (d, BV)
-    dots = jax.lax.dot_general(
-        q, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    score = vn[None, :] - 2.0 * dots
+    valid = valid_ref[...] != 0                 # (1, BV)
+    score = sq_norms_row(v) - 2.0 * dots_qt(q, v)
     out_ref[...] = jnp.where(valid, score, BIG)
 
 
@@ -49,8 +68,9 @@ def _kernel(q_ref, v_ref, valid_ref, out_ref):
 def posting_scan(q: jax.Array, v: jax.Array, valid: jax.Array,
                  *, bq: int = DEFAULT_BQ, bv: int = DEFAULT_BV,
                  interpret: bool = False) -> jax.Array:
-    """Padded-shape Pallas entry.  Q % bq == 0, V % bv == 0, d % 128 == 0
-    are guaranteed by the ops.py wrapper."""
+    """Padded-shape Pallas entry: q (Q, d), v (V, d), valid (1, V) int32.
+    Q % bq == 0, V % bv == 0, d % 128 == 0 are guaranteed by the ops.py
+    wrapper.  Returns (Q, V) f32 scores, +BIG at invalid slots."""
     Q, d = q.shape
     V = v.shape[0]
     grid = (Q // bq, V // bv)
@@ -69,68 +89,16 @@ def posting_scan(q: jax.Array, v: jax.Array, valid: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Scalar-prefetch gather variant: postings stream from HBM by probe index.
-#
-# The search phase-2 working set is per-query: each query scans only the
-# ``nprobe`` postings its phase-1 filter chose.  Materialising the gather
-# (Q, P, C, d) in HBM doubles traffic; instead the probe table is scalar-
-# prefetched and each grid step DMAs exactly one posting tile HBM->VMEM
-# (Pallas double-buffers consecutive steps).  Arithmetic intensity of the
-# scan is ~1 FLOP/byte, so this kernel is *bandwidth*-bound by design —
-# the win is eliminating the gather round-trip, not MXU utilisation.
-# ---------------------------------------------------------------------------
-
-
-def _gather_kernel(probe_ref, q_ref, v_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)            # (1, d)
-    v = v_ref[0].astype(jnp.float32)              # (C, d)
-    vn = jnp.sum(v * v, axis=-1)                  # (C,)
-    dots = jax.lax.dot_general(
-        v, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                             # (C, 1)
-    o_ref[0, 0] = vn - 2.0 * dots[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def posting_scan_gather(q: jax.Array, vectors: jax.Array, probe: jax.Array,
-                        *, interpret: bool = False) -> jax.Array:
-    """q: (Q, dp); vectors: (M, Cp, dp); probe: (Q, P) int32 posting ids.
-
-    Returns raw scores (Q, P, Cp); validity masking is applied by the
-    ops.py wrapper (slot/visibility masks never enter the kernel), which
-    also zero-pads d and C up to 128 multiples (zero-padding d is
-    fp-exact for both the norm and the dot) and slices the logical
-    (Q, P, C) block back out — the assertion below never fires.
-    """
-    Q, d = q.shape
-    M, C, _ = vectors.shape
-    P = probe.shape[1]
-    assert d % 128 == 0 and C % 128 == 0, (d, C)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Q, P),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, probe: (i, 0)),
-            pl.BlockSpec((1, C, d), lambda i, j, probe: (probe[i, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, C), lambda i, j, probe: (i, j, 0)),
-    )
-    return pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, P, C), jnp.float32),
-        interpret=interpret,
-    )(probe, q, vectors)
-
-
-# ---------------------------------------------------------------------------
 # Fused gather scan + on-chip top-k (float phase-2 twin of
-# ``pq_scan.pq_scan_topk``): same double-buffered probe-indexed tile
-# streaming as the gather kernel above, but the (Q, P, C) score tensor
-# never hits HBM — a running top-k (score, flat-slot) list per query is
-# carried in the output refs (``merge_topk``, the flash-attention
-# online-reduction idiom), with validity and per-(query, probe)
-# ownership masks applied in-kernel before selection.
+# ``pq_scan.pq_scan_topk``).  The probe table is scalar-prefetched and
+# each grid step (i, j) DMAs exactly one posting tile HBM->VMEM (Pallas
+# double-buffers consecutive steps); the (Q, P, C) score tensor never
+# hits HBM — a running top-k (score, flat-slot) list per query is carried
+# in the output refs (``merge_topk``, the flash-attention online-reduction
+# idiom), with validity and per-(query, probe) ownership masks applied
+# in-kernel before selection.  Per-query and per-posting rows carry a
+# unit middle axis ((Q, 1, dp), (M, 1, Cp), (Q, 1, k)) so that every
+# block's last two dims equal the array's, as Mosaic requires.
 # ---------------------------------------------------------------------------
 
 
@@ -146,12 +114,10 @@ def _gather_topk_kernel(probe_ref, ok_ref, q_ref, v_ref, valid_ref,
         i_ref[...] = jnp.zeros_like(i_ref)
 
     q = q_ref[...].astype(jnp.float32)            # (1, dp)
-    v = v_ref[0].astype(jnp.float32)              # (Cp, dp)
+    v = v_ref[...].astype(jnp.float32)            # (Cp, dp)
     Cp = v.shape[0]
-    vn = jnp.sum(v * v, axis=-1)                  # (Cp,)
-    dots = jax.lax.dot_general(
-        v, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                             # (Cp, 1)
+    vn = jnp.sum(v * v, axis=-1, keepdims=True)   # (Cp, 1)
+    raw = lane_row(vn - 2.0 * dots_qt(v, q))      # (1, Cp)
     # slots beyond the LOGICAL capacity ``c`` are wrapper padding: +inf
     # (never selectable — the wrapper guarantees k <= P*c real
     # candidates, all <= BIG < inf) keeps the BIG-tie order of real
@@ -159,9 +125,8 @@ def _gather_topk_kernel(probe_ref, ok_ref, q_ref, v_ref, valid_ref,
     # stride so flat ids match the ref twin bit-for-bit.
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, Cp), 1)
     in_lane = lane < c
-    ok = valid_ref[...] & (ok_ref[i, j] != 0) & in_lane   # (1, Cp)
-    score = jnp.where(ok, (vn - 2.0 * dots[:, 0])[None, :],
-                      jnp.where(in_lane, BIG, jnp.inf))
+    ok = (valid_ref[...] != 0) & (ok_ref[i, j] != 0) & in_lane
+    score = jnp.where(ok, raw, jnp.where(in_lane, BIG, jnp.inf))
     cand = lane + probe_ref[i, j] * c
     s, ids = merge_topk(s_ref[...], i_ref[...], score, cand, k)
     s_ref[...] = s
@@ -174,13 +139,13 @@ def posting_scan_topk(q: jax.Array, vectors: jax.Array, valid: jax.Array,
                       *, k: int, c: int, interpret: bool = False):
     """Fused probe scan + running top-k.
 
-    q: (Q, dp); vectors: (M, Cp, dp); valid: (M, Cp) bool (slot validity
-    & posting visibility, precombined; padding lanes False); qp_ok:
-    (Q, P) int32 per-(query, probe) mask; probe: (Q, P) int32.  ``c`` is
-    the LOGICAL posting capacity — lanes in [c, Cp) are wrapper padding,
-    masked in-kernel via an iota-vs-extent mask.  Returns (scores (Q, k)
-    f32 ascending, cand (Q, k) int32 flat slot index ``probe*c + lane``);
-    masked candidates carry BIG.  Bit-identical to
+    q: (Q, dp); vectors: (M, Cp, dp); valid: (M, Cp) int32 (slot
+    validity & posting visibility, precombined; padding lanes 0);
+    qp_ok: (Q, P) int32 per-(query, probe) mask; probe: (Q, P) int32.
+    ``c`` is the LOGICAL posting capacity — lanes in [c, Cp) are wrapper
+    padding, masked in-kernel via an iota-vs-extent mask.  Returns
+    (scores (Q, k) f32 ascending, cand (Q, k) int32 flat slot index
+    ``probe*c + lane``); masked candidates carry BIG.  Bit-identical to
     ``ref.posting_scan_topk`` including tie order.  Storage shapes
     arrive 128-aligned from the ops.py wrapper (the assertions below
     never fire).
@@ -188,29 +153,29 @@ def posting_scan_topk(q: jax.Array, vectors: jax.Array, valid: jax.Array,
     Q, d = q.shape
     M, C, _ = vectors.shape
     P = probe.shape[1]
-    assert d % 128 == 0 and C % 128 == 0, (d, C)
+    assert d % 128 == 0 and C % 8 == 0, (d, C)
     assert 0 < c <= C, (c, C)
+    row = lambda n: pl.BlockSpec((None, 1, n),
+                                 lambda i, j, probe, ok: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Q, P),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, probe, ok: (i, 0)),
-            pl.BlockSpec((1, C, d),
+            row(d),
+            pl.BlockSpec((None, C, d),
                          lambda i, j, probe, ok: (probe[i, j], 0, 0)),
-            pl.BlockSpec((1, C),
-                         lambda i, j, probe, ok: (probe[i, j], 0)),
+            pl.BlockSpec((None, 1, C),
+                         lambda i, j, probe, ok: (probe[i, j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, probe, ok: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, probe, ok: (i, 0)),
-        ],
+        out_specs=[row(k), row(k)],
     )
-    return pl.pallas_call(
+    s, ids = pl.pallas_call(
         functools.partial(_gather_topk_kernel, k=k, c=c),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(probe, qp_ok, q, vectors, valid)
+    )(probe, qp_ok, q.reshape(Q, 1, d), vectors, valid.reshape(M, 1, C))
+    return s.reshape(Q, k), ids.reshape(Q, k)

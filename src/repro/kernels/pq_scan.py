@@ -1,26 +1,33 @@
-"""Pallas TPU kernel: ADC lookup-table scan over packed PQ codes.
+"""Pallas TPU kernel: fused ADC scan + top-k over packed PQ codes.
 
-The quant-plane twin of ``posting_scan.py``'s gather kernel: for each
-(query, probe) pair, stream one posting's uint8 code tile HBM->VMEM and
-accumulate per-subspace lookup-table entries.  The probe table AND the
+The quant-plane twin of ``posting_scan.posting_scan_topk``: for each
+(query, probe) pair, stream one posting's uint8 code tile HBM->VMEM,
+accumulate per-subspace lookup-table entries, and merge the tile into a
+running per-query top-k held in the output refs (``merge_topk``, the
+flash-attention online-reduction idiom).  The probe table AND the
 per-posting codebook-slot table are scalar-prefetched, so the lookup
 table block for grid step (i, j) is selected by the *probed posting's*
-codebook version — versioned codebooks cost one extra scalar indirection,
-not a second pass.
+codebook version — versioned codebooks cost one extra scalar
+indirection, not a second pass.  No (Q, P, C) score tensor ever hits
+HBM: the kernel writes 2*k words per query.
 
 The in-kernel gather is expressed as ``m`` small one-hot matmuls
-(code -> one-hot (C, ksub) on the VPU, one-hot @ lut[j] on the MXU):
-TPU has no per-lane dynamic gather, but ksub <= 256 keeps each one-hot
-block a single (C, 256) tile.  Arithmetic intensity is higher than the
-float scan by design — C*m bytes of codes per posting instead of
-C*d*4 — which is the whole point of the quant plane.
+(code row -> one-hot (ksub, C) on the VPU, lut row @ one-hot on the
+MXU): TPU has no per-lane dynamic gather, but ksub <= 256 keeps each
+one-hot block a single (256, C) tile.  Each product has exactly one
+non-zero term, so the sum is exact.  Arithmetic intensity is higher
+than the float scan by design — C*m bytes of codes per posting instead
+of C*d*4 — which is the whole point of the quant plane.
+
+The validity mask (slot_valid & vis, precombined by ops.py into one
+(M, C) row table) and the per-(query, probe) mask (the sharded plane's
+``mine``) are applied in-kernel *before* selection — post-hoc masking
+is impossible once top-k is fused.
 
     luts  : (Q, V, m, ksub) f32   per-query per-slot ADC tables
     codes : (M, m, C) uint8       subspace-major code tiles
     slot  : (M,) int32            codebook slot per posting (prefetched)
     probe : (Q, P) int32          posting ids per query (prefetched)
-Output:
-    score : (Q, P, C) f32 raw ADC scores (masking done by the wrapper)
 """
 from __future__ import annotations
 
@@ -31,74 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .posting_scan import BIG
-
-
-def _kernel(probe_ref, slot_ref, lut_ref, codes_ref, o_ref):
-    del probe_ref, slot_ref                       # consumed by index maps
-    lut = lut_ref[0, 0].astype(jnp.float32)       # (m, ksub)
-    code = codes_ref[0].astype(jnp.int32)         # (m, C)
-    m, C = code.shape
-    ksub = lut.shape[1]
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (C, ksub), 1)
-    acc = jnp.zeros((C,), jnp.float32)
-    for j in range(m):                            # static unroll, m small
-        onehot = (code[j][:, None] == k_iota).astype(jnp.float32)
-        acc = acc + jax.lax.dot_general(
-            onehot, lut[j], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    o_ref[0, 0] = acc
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def pq_scan_gather(luts: jax.Array, codes: jax.Array, slot: jax.Array,
-                   probe: jax.Array, *, interpret: bool = False
-                   ) -> jax.Array:
-    """Padded-shape Pallas entry.  The ops.py wrapper zero-pads ``C``
-    and ``ksub`` up to 128 multiples (exactly neutral: codes < logical
-    ksub never hit padded lut columns) and slices the logical (Q, P, C)
-    block back out — so the assertions below never fire."""
-    Q, V, m, ksub = luts.shape
-    M, _, C = codes.shape
-    P = probe.shape[1]
-    assert C % 128 == 0 and ksub % 128 == 0, (C, ksub)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Q, P),
-        in_specs=[
-            pl.BlockSpec((1, 1, m, ksub),
-                         lambda i, j, probe, slot: (i, slot[probe[i, j]],
-                                                    0, 0)),
-            pl.BlockSpec((1, m, C),
-                         lambda i, j, probe, slot: (probe[i, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, C),
-                               lambda i, j, probe, slot: (i, j, 0)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Q, P, C), jnp.float32),
-        interpret=interpret,
-    )(probe, slot, luts, codes)
-
-
-# ---------------------------------------------------------------------------
-# Fused ADC scan + on-chip top-k: the (Q, P, C) score tensor above only
-# exists to feed ``lax.top_k`` — at nprobe=32, C=128 that is 16 KiB of
-# HBM write+read per query for <= rerank_k survivors.  This variant
-# keeps a running top-k (score, flat-candidate) list per query in the
-# output refs (``merge_topk``, the flash-attention online-reduction
-# idiom) while the scalar-prefetched probe list streams exactly one
-# posting's code tile HBM->VMEM per grid step; Pallas double-buffers
-# consecutive steps' tile DMAs against the current step's compute.  No
-# score matrix ever hits HBM: the kernel writes 2*k words per query.
-#
-# The validity mask (slot_valid & vis, precombined by ops.py into one
-# (M, C) row table) and the per-(query, probe) mask (the sharded plane's
-# ``mine``) are applied in-kernel *before* selection — post-hoc masking
-# is impossible once top-k is fused.
-# ---------------------------------------------------------------------------
+from .posting_scan import BIG, HIGHEST
 
 
 def _topk_kernel(probe_ref, slot_ref, ok_ref, lut_ref, codes_ref,
@@ -112,17 +52,17 @@ def _topk_kernel(probe_ref, slot_ref, ok_ref, lut_ref, codes_ref,
         s_ref[...] = jnp.full_like(s_ref, jnp.inf)
         i_ref[...] = jnp.zeros_like(i_ref)
 
-    lut = lut_ref[0, 0].astype(jnp.float32)       # (m, ksub)
-    code = codes_ref[0].astype(jnp.int32)         # (m, Cp)
+    lut = lut_ref[...].astype(jnp.float32)        # (m, ksub)
+    code = codes_ref[...].astype(jnp.int32)       # (m, Cp)
     m, Cp = code.shape
     ksub = lut.shape[1]
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (Cp, ksub), 1)
-    acc = jnp.zeros((Cp,), jnp.float32)
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (ksub, Cp), 0)
+    acc = jnp.zeros((1, Cp), jnp.float32)
     for jj in range(m):                           # static unroll, m small
-        onehot = (code[jj][:, None] == k_iota).astype(jnp.float32)
+        onehot = (code[jj:jj + 1, :] == k_iota).astype(jnp.float32)
         acc = acc + jax.lax.dot_general(
-            onehot, lut[jj], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            lut[jj:jj + 1, :], onehot, (((1,), (0,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32)
     # lanes beyond the LOGICAL capacity ``c`` are wrapper padding: mask
     # them to +inf (never selectable: the wrapper guarantees k <= P*c
     # real candidates, all <= BIG < inf) so they cannot perturb the
@@ -130,9 +70,8 @@ def _topk_kernel(probe_ref, slot_ref, ok_ref, lut_ref, codes_ref,
     # with the logical stride so flat ids match the ref twin exactly.
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, Cp), 1)
     in_lane = lane < c
-    ok = valid_ref[...] & (ok_ref[i, j] != 0) & in_lane   # (1, Cp)
-    score = jnp.where(ok, acc[None, :],
-                      jnp.where(in_lane, BIG, jnp.inf))   # (1, Cp)
+    ok = (valid_ref[...] != 0) & (ok_ref[i, j] != 0) & in_lane  # (1, Cp)
+    score = jnp.where(ok, acc, jnp.where(in_lane, BIG, jnp.inf))
     cand = lane + probe_ref[i, j] * c
     s, ids = merge_topk(s_ref[...], i_ref[...], score, cand, k)
     s_ref[...] = s
@@ -146,45 +85,47 @@ def pq_scan_topk(luts: jax.Array, codes: jax.Array, slot: jax.Array,
     """Fused ADC scan + running top-k.
 
     luts: (Q, V, m, ksub) f32; codes: (M, m, Cp) uint8; slot: (M,) int32;
-    valid: (M, Cp) bool (slot_valid & posting visibility, precombined,
-    padding lanes False); qp_ok: (Q, P) int32 per-(query, probe) mask;
+    valid: (M, Cp) int32 (slot_valid & posting visibility, precombined,
+    padding lanes 0); qp_ok: (Q, P) int32 per-(query, probe) mask;
     probe: (Q, P) int32.  ``c`` is the LOGICAL posting capacity; lanes
     in [c, Cp) are wrapper padding, masked in-kernel via an
     iota-vs-extent mask.  Returns (scores (Q, k) f32 ascending, cand
     (Q, k) int32 flat slot index ``probe*c + lane``); masked candidates
     carry BIG.  Bit-identical to ``ref.pq_scan_topk`` including tie
     order (probe-position-major).  Storage shapes arrive 128-aligned
-    from the ops.py wrapper (assertions below never fire).
+    from the ops.py wrapper (assertions below never fire).  Per-posting
+    and per-query rows carry a unit middle axis so that every block's
+    last two dims equal the array's, as Mosaic requires.
     """
     Q, V, m, ksub = luts.shape
     M, _, C = codes.shape
     P = probe.shape[1]
-    assert C % 128 == 0 and ksub % 128 == 0, (C, ksub)
+    assert C % 8 == 0 and ksub % 128 == 0, (C, ksub)
     assert 0 < c <= C, (c, C)
+    out_row = pl.BlockSpec((None, 1, k),
+                           lambda i, j, probe, slot, ok: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(Q, P),
         in_specs=[
-            pl.BlockSpec((1, 1, m, ksub),
+            pl.BlockSpec((None, None, m, ksub),
                          lambda i, j, probe, slot, ok: (i,
                                                         slot[probe[i, j]],
                                                         0, 0)),
-            pl.BlockSpec((1, m, C),
+            pl.BlockSpec((None, m, C),
                          lambda i, j, probe, slot, ok: (probe[i, j], 0, 0)),
-            pl.BlockSpec((1, C),
-                         lambda i, j, probe, slot, ok: (probe[i, j], 0)),
+            pl.BlockSpec((None, 1, C),
+                         lambda i, j, probe, slot, ok: (probe[i, j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, probe, slot, ok: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, probe, slot, ok: (i, 0)),
-        ],
+        out_specs=[out_row, out_row],
     )
-    return pl.pallas_call(
+    s, ids = pl.pallas_call(
         functools.partial(_topk_kernel, k=k, c=c),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(probe, slot, qp_ok, luts, codes, valid)
+    )(probe, slot, qp_ok, luts, codes, valid.reshape(M, 1, C))
+    return s.reshape(Q, k), ids.reshape(Q, k)

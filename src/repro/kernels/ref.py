@@ -16,6 +16,27 @@ import jax
 import jax.numpy as jnp
 
 BIG = 1e30  # masked-score sentinel shared with the Pallas kernels
+# f32 matmuls at full precision, as in the Pallas kernels (a TPU's
+# default precision rounds f32 operands to bfloat16)
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def exact_argmin(x: jax.Array, axis: int = -1) -> jax.Array:
+    """``jnp.argmin`` (lowest index among ties) that stays exact on a
+    TPU.  XLA's TPU backend keeps the unused value half of argmin's
+    (value, index) reduction in bfloat16, so candidates within bf16
+    resolution of the minimum (~0.4% relative) can win: on a v5e this
+    made 57% of PQ codes differ from a float64 encode.  A f32 min
+    followed by a first-match search of the equality mask does not
+    narrow.  Returns int32."""
+    best = jnp.min(x, axis=axis, keepdims=True)
+    return jnp.argmax(x == best, axis=axis).astype(jnp.int32)
+
+
+def exact_argmax(x: jax.Array, axis: int = -1) -> jax.Array:
+    """``jnp.argmax`` counterpart of :func:`exact_argmin`."""
+    best = jnp.max(x, axis=axis, keepdims=True)
+    return jnp.argmax(x == best, axis=axis).astype(jnp.int32)
 
 
 def centroid_score(queries: jax.Array, centroids: jax.Array) -> jax.Array:
@@ -23,7 +44,7 @@ def centroid_score(queries: jax.Array, centroids: jax.Array) -> jax.Array:
     q = queries.astype(jnp.float32)
     c = centroids.astype(jnp.float32)
     cn = jnp.sum(c * c, axis=-1)
-    return cn[None, :] - 2.0 * (q @ c.T)
+    return cn[None, :] - 2.0 * jnp.matmul(q, c.T, precision=HIGHEST)
 
 
 def posting_scan(queries: jax.Array, tiles: jax.Array,
@@ -38,7 +59,7 @@ def posting_scan(queries: jax.Array, tiles: jax.Array,
     G, C, d = tiles.shape
     v = tiles.reshape(G * C, d).astype(jnp.float32)
     vn = jnp.sum(v * v, axis=-1)
-    s = vn[None, :] - 2.0 * (q @ v.T)
+    s = vn[None, :] - 2.0 * jnp.matmul(q, v.T, precision=HIGHEST)
     return jnp.where(valid.reshape(1, G * C), s, BIG)
 
 
@@ -51,7 +72,7 @@ def kmeans_assign(points: jax.Array, centroids: jax.Array,
     assignment -1 and score +inf.
     """
     s = centroid_score(points, centroids)  # (N, K)
-    assign = jnp.argmin(s, axis=-1).astype(jnp.int32)
+    assign = exact_argmin(s)
     best = jnp.min(s, axis=-1)
     if mask is not None:
         assign = jnp.where(mask, assign, -1)
@@ -97,7 +118,7 @@ def pq_scan_gather(luts: jax.Array, codes: jax.Array, slot: jax.Array,
     codes: (M, m, C) uint8 subspace-major codes; slot: (M,) int32
     codebook slot of each posting; probe: (Q, P) int32.
     Returns raw (Q, P, C) scores ``sum_j lut[slot[p], j, code[j, c]]``
-    (validity masking is the wrapper's job, as in posting_scan_gather).
+    (validity masking is the caller's job — ``pq_scan_topk`` applies it).
     """
     Q, V, m, ksub = luts.shape
     codes_g = codes[probe].astype(jnp.int32)                # (Q, P, m, C)
@@ -164,7 +185,7 @@ def posting_scan_topk(queries: jax.Array, vectors: jax.Array,
     tiles = vectors[probe].astype(jnp.float32)              # (Q, P, C, d)
     Q, P, C, _ = tiles.shape
     vn = jnp.sum(tiles * tiles, axis=-1)
-    dots = jnp.einsum("qd,qpcd->qpc", q, tiles)
+    dots = jnp.einsum("qd,qpcd->qpc", q, tiles, precision=HIGHEST)
     ok = valid[probe] & (qp_ok != 0)[:, :, None]
     s = jnp.where(ok, vn - 2.0 * dots, BIG)
     neg, pos = jax.lax.top_k(-s.reshape(Q, P * C), k)
@@ -192,7 +213,7 @@ def rerank_topk(queries: jax.Array, vectors: jax.Array,
     q = queries.astype(jnp.float32)
     cv = vectors.reshape(M * C, d)[cand].astype(jnp.float32)  # (Q, R, d)
     exact = (jnp.sum(cv * cv, -1)
-             - 2.0 * jnp.einsum("qd,qrd->qr", q, cv))
+             - 2.0 * jnp.einsum("qd,qrd->qr", q, cv, precision=HIGHEST))
     exact = jnp.where(tier_spilled[cand // C], adc, exact)
     exact = jnp.where(adc < BIG / 2, exact, BIG)
     neg, pos = jax.lax.top_k(-exact, k)
@@ -211,7 +232,7 @@ def posting_scan_gather(queries: jax.Array, vectors: jax.Array,
     q = queries.astype(jnp.float32)
     tiles = vectors[probe].astype(jnp.float32)          # (Q, P, C, d)
     vn = jnp.sum(tiles * tiles, axis=-1)
-    dots = jnp.einsum("qd,qpcd->qpc", q, tiles)
+    dots = jnp.einsum("qd,qpcd->qpc", q, tiles, precision=HIGHEST)
     s = vn - 2.0 * dots
     ok = slot_valid[probe] & vis[probe][..., None]
     return jnp.where(ok, s, BIG)

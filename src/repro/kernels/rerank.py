@@ -10,24 +10,25 @@ HBM->VMEM (Pallas double-buffers consecutive steps), computes
 ``||v||^2 - 2 q.v`` on the VPU, substitutes the ADC score for
 tier-spilled candidates (cold-tier plane: their device float tile is
 zeroed, so the ADC score IS their serving score), masks empty ADC slots
-to BIG, and merges into a running per-query top-k carried in the output
-refs (``merge_topk``, the same online-reduction idiom as the other
-fused kernels).  No (Q, R, d) gather and no (Q, R) score row ever hit
-HBM: the kernel writes 2*Q*k words.
+to BIG, and writes the score into lane r of a (1, R) VMEM row.  The
+last step of each query selects the top-k of that row on-chip.  No
+(Q, R, d) gather and no (Q, R) score row ever hit HBM: the kernel
+writes 2*Q*k words.
 
     q       : (Q, dp) f32        queries (d zero-padded to 128)
     vflat   : (M*C, dp) f32      posting pool viewed as flat slot rows
-    cand    : (Q, R) int32       flat slot candidates (prefetched)
+    cand    : (Q, R) int32       flat slot candidates (prefetched, and
+                                 read as a row for the final selection)
     adc     : (Q, R) f32         the candidates' ADC scores
     spilled : (Q, R) int32       1 where the candidate's posting is
                                  tier-spilled (serve the ADC score)
 Output:
     scores  : (Q, k) f32 ascending;  cand_out : (Q, k) int32
 
-Tie discipline: candidates are visited in ADC-rank order r and the
-running list orders equal scores by arrival, so ties break
-lowest-r-first — exactly ``lax.top_k`` over the (Q, R) exact row, which
-makes the ref twin (``ref.rerank_topk``) bit-identical.
+Tie discipline: the final selection takes the lowest lane on equal
+scores, so ties break lowest-r-first — exactly ``lax.top_k`` over the
+(Q, R) exact row, which makes the ref twin (``ref.rerank_topk``)
+bit-identical.
 """
 from __future__ import annotations
 
@@ -41,30 +42,36 @@ import jax.experimental.pallas.tpu as pltpu
 from .posting_scan import BIG
 
 
-def _kernel(cand_ref, q_ref, v_ref, adc_ref, sp_ref, s_ref, i_ref, *, k):
-    from .centroid_topk import merge_topk
-    i = pl.program_id(0)
+def _kernel(cand_ref, q_ref, v_ref, adc_ref, sp_ref, cand_row_ref,
+            s_ref, i_ref, row_ref, *, k):
+    from .centroid_topk import topk_rows
     r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        s_ref[...] = jnp.full_like(s_ref, jnp.inf)
-        i_ref[...] = jnp.zeros_like(i_ref)
+    R = row_ref.shape[1]
 
     q = q_ref[...].astype(jnp.float32)            # (1, dp)
     v = v_ref[...].astype(jnp.float32)            # (1, dp)
-    adc = adc_ref[0, 0]
-    exact = jnp.sum(v * v) - 2.0 * jnp.sum(q * v)
+    exact = (jnp.sum(v * v, axis=-1, keepdims=True)
+             - 2.0 * jnp.sum(q * v, axis=-1, keepdims=True))   # (1, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+    at_r = lane == r
+    # this candidate's ADC score and spill flag, read out of their rows
+    # (exact: every other lane is the reduction's identity)
+    adc = jnp.min(jnp.where(at_r, adc_ref[...], jnp.inf), axis=-1,
+                  keepdims=True)
+    spilled = jnp.max(jnp.where(at_r, sp_ref[...], 0), axis=-1,
+                      keepdims=True)
     # cold-tier passthrough: spilled candidates keep their ADC score
     # (their float row is zeroed); empty ADC slots stay BIG so the
     # final merge's ``score < BIG/2`` id masking keeps working.
-    score = jnp.where(sp_ref[0, 0] != 0, adc, exact)
+    score = jnp.where(spilled != 0, adc, exact)
     score = jnp.where(adc < BIG / 2, score, BIG)
-    tile_s = jnp.full((1, 1), score, jnp.float32)
-    tile_i = jnp.full((1, 1), cand_ref[i, r], jnp.int32)
-    s, ids = merge_topk(s_ref[...], i_ref[...], tile_s, tile_i, k)
-    s_ref[...] = s
-    i_ref[...] = ids
+    row_ref[...] = jnp.where(at_r, score, row_ref[...])
+
+    @pl.when(r == R - 1)
+    def _select():
+        s, ids = topk_rows(row_ref[...], cand_row_ref[...], k)
+        s_ref[...] = s
+        i_ref[...] = ids
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -74,31 +81,36 @@ def rerank_topk(q: jax.Array, vflat: jax.Array, cand: jax.Array,
     """Padded-shape Pallas entry.  q: (Q, dp); vflat: (M*C, dp); cand:
     (Q, R) int32 in [0, M*C); adc/spilled: (Q, R).  The ops.py wrapper
     zero-pads d up to a 128 multiple (fp-exact) — the assertion below
-    never fires.  Returns (scores (Q, k) ascending, cand (Q, k))."""
+    never fires.  Returns (scores (Q, k) ascending, cand (Q, k)).
+    Per-query and per-slot rows carry a unit middle axis so that every
+    block's last two dims equal the array's, as Mosaic requires."""
     Q, d = q.shape
+    N = vflat.shape[0]
     R = cand.shape[1]
     assert d % 128 == 0, d
     assert 0 < k <= R, (k, R)
+    cand = cand.astype(jnp.int32)
+    row = lambda n: pl.BlockSpec((None, 1, n), lambda i, r, cand: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q, R),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, r, cand: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, r, cand: (cand[i, r], 0)),
-            pl.BlockSpec((1, 1), lambda i, r, cand: (i, r)),
-            pl.BlockSpec((1, 1), lambda i, r, cand: (i, r)),
+            row(d),
+            pl.BlockSpec((None, 1, d), lambda i, r, cand: (cand[i, r], 0, 0)),
+            row(R), row(R), row(R),
         ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, r, cand: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, r, cand: (i, 0)),
-        ],
+        out_specs=[row(k), row(k)],
+        scratch_shapes=[pltpu.VMEM((1, R), jnp.float32)],
     )
-    return pl.pallas_call(
+    s, ids = pl.pallas_call(
         functools.partial(_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((Q, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(cand.astype(jnp.int32), q, vflat, adc, spilled.astype(jnp.int32))
+    )(cand, q.reshape(Q, 1, d), vflat.reshape(N, 1, d),
+      adc.reshape(Q, 1, R), spilled.astype(jnp.int32).reshape(Q, 1, R),
+      cand.reshape(Q, 1, R))
+    return s.reshape(Q, k), ids.reshape(Q, k)

@@ -102,8 +102,7 @@ class RetrievalServer:
         self._embedder: Optional[EmbeddingServer] = None
         if index_cfg is None:
             index_cfg = UBISConfig(dim=cfg.embed_dim, max_postings=2048,
-                                   capacity=96, max_ids=1 << 20,
-                                   use_pallas="off")
+                                   capacity=96, max_ids=1 << 20)
         if seed_vectors is None:
             seed_vectors = np.random.default_rng(cfg.seed).normal(
                 size=(1024, index_cfg.dim)).astype(np.float32)
@@ -215,6 +214,8 @@ def main(argv=None):
                     help="print the Prometheus exposition at exit")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = ServeConfig(arch=args.arch, tick_every=args.tick_every,
                       recall_probe=args.recall_probe,
                       obs_trace_path=args.obs_trace_path,

@@ -88,25 +88,16 @@ class Obs:
 
     @contextmanager
     def profile(self, trace_dir: Optional[str]):
-        """Wrap a block in a ``jax.profiler`` trace capture.
-
-        Best-effort: if the profiler backend is unavailable (e.g. a
-        second concurrent capture) the block still runs untraced.
-        """
-        started = False
-        if trace_dir:
-            try:
-                import jax
-                jax.profiler.start_trace(str(trace_dir))
-                started = True
-            except Exception:
-                started = False
+        """Wrap a block in a ``jax.profiler`` trace written under
+        ``trace_dir`` (``None``: run the block untraced).  A trace that
+        was asked for and cannot start raises — a run that silently
+        carries on untraced would be read as a traced one."""
+        if not trace_dir:
+            yield
+            return
+        import jax
+        jax.profiler.start_trace(str(trace_dir))
         try:
             yield
         finally:
-            if started:
-                try:
-                    import jax
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass
+            jax.profiler.stop_trace()

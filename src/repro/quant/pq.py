@@ -29,25 +29,42 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ops
+from ..kernels import ops, ref
 
 
 # ---------------------------------------------------------------------------
 # encode / decode / lookup tables (pure functions of one codebook set)
 # ---------------------------------------------------------------------------
 
-def encode(codebooks: jax.Array, x: jax.Array) -> jax.Array:
-    """Nearest-centroid codes per subspace.
+# rows encoded per step: the (m, rows, ksub) f32 score block stays
+# at 256 MiB for m=16, ksub=256 (a whole 3M-row pool at once is 51 GB)
+ENCODE_ROWS = 16384
 
-    codebooks: (m, ksub, dsub) f32; x: (N, d) -> (N, m) uint8.
-    """
+
+def _encode_rows(codebooks: jax.Array, x: jax.Array) -> jax.Array:
     m, ksub, dsub = codebooks.shape
     n = x.shape[0]
     xs = x.astype(jnp.float32).reshape(n, m, dsub).transpose(1, 0, 2)
     cn = jnp.sum(codebooks * codebooks, axis=-1)            # (m, ksub)
-    dots = jnp.einsum("jnd,jkd->jnk", xs, codebooks)        # (m, N, ksub)
+    dots = jnp.einsum("jnd,jkd->jnk", xs, codebooks,
+                      precision=ref.HIGHEST)                # (m, N, ksub)
     scores = cn[:, None, :] - 2.0 * dots
-    return jnp.argmin(scores, axis=-1).astype(jnp.uint8).T  # (N, m)
+    return ref.exact_argmin(scores).astype(jnp.uint8).T    # (N, m)
+
+
+def encode(codebooks: jax.Array, x: jax.Array) -> jax.Array:
+    """Nearest-centroid codes per subspace, ``ENCODE_ROWS`` rows at a
+    time.
+
+    codebooks: (m, ksub, dsub) f32; x: (N, d) -> (N, m) uint8.
+    """
+    n, d = x.shape
+    if n <= ENCODE_ROWS:
+        return _encode_rows(codebooks, x)
+    xb = jnp.pad(x, ((0, -n % ENCODE_ROWS), (0, 0)))
+    codes = jax.lax.map(lambda b: _encode_rows(codebooks, b),
+                        xb.reshape(-1, ENCODE_ROWS, d))
+    return codes.reshape(-1, codebooks.shape[0])[:n]
 
 
 def encode_all_versions(codebooks_v: jax.Array, x: jax.Array) -> jax.Array:
@@ -87,16 +104,17 @@ def lookup_tables(codebooks_v: jax.Array, queries: jax.Array) -> jax.Array:
     Q = queries.shape[0]
     qs = queries.astype(jnp.float32).reshape(Q, m, dsub)
     cn = jnp.sum(codebooks_v * codebooks_v, axis=-1)        # (V, m, ksub)
-    dots = jnp.einsum("qjd,sjkd->qsjk", qs, codebooks_v)
+    dots = jnp.einsum("qjd,sjkd->qsjk", qs, codebooks_v,
+                      precision=ref.HIGHEST)
     return cn[None] - 2.0 * dots
 
 
 # ---------------------------------------------------------------------------
-# codebook training — vmapped masked Lloyd per subspace
+# codebook training — masked Lloyd per subspace
 # ---------------------------------------------------------------------------
 
 def train_codebooks(sample: jax.Array, mask: jax.Array, init: jax.Array,
-                    iters: int, *, backend: str = "ref") -> jax.Array:
+                    iters: int, *, backend: str = "off") -> jax.Array:
     """Refine codebooks on a (masked) sample, one k-means per subspace.
 
     sample: (S, d); mask: (S,) bool; init: (m, ksub, dsub) warm-start
@@ -104,7 +122,10 @@ def train_codebooks(sample: jax.Array, mask: jax.Array, init: jax.Array,
     previous generation on fresh data; empty clusters keep their old
     centroid instead of collapsing).  The assignment step reuses the
     ``kernels/kmeans_assign`` op per subspace; ``backend`` follows the
-    repo-wide dispatch (vmap over subspaces batches the Pallas call).
+    repo-wide dispatch.  Subspaces run one after another (``lax.map``):
+    the compiled kernel under ``vmap`` returned wrong assignments on a
+    TPU v5e (left rows at the BIG sentinel) while the unbatched kernel
+    matched its twin, and m small calls cost next to nothing.
     """
     m, ksub, dsub = init.shape
     S = sample.shape[0]
@@ -124,17 +145,48 @@ def train_codebooks(sample: jax.Array, mask: jax.Array, init: jax.Array,
 
         return jax.lax.fori_loop(0, iters, body, cents)
 
-    return jax.vmap(lloyd)(pts, init.astype(jnp.float32))
+    return jax.lax.map(lambda pc: lloyd(*pc),
+                       (pts, init.astype(jnp.float32)))
+
+
+def _kmeanspp(points: jax.Array, k: int, key: jax.Array) -> jax.Array:
+    """k-means++ seeding of one subspace: (n, dsub) -> (k, dsub).
+
+    Each next centroid is drawn with probability proportional to its
+    squared distance from the nearest one chosen so far, so every
+    well-separated region of the sample gets centroids in proportion to
+    its spread.  (Seeding from random sample rows leaves a region that
+    drew a single row with one centroid — Lloyd cannot move others
+    across the gap — and every vector there then shares one code.)"""
+    n = points.shape[0]
+    first = points[jax.random.randint(key, (), 0, n)]
+    d2 = jnp.sum((points - first) ** 2, -1)
+    cents = jnp.zeros((k, points.shape[1]), jnp.float32).at[0].set(first)
+
+    def body(i, carry):
+        cents, d2 = carry
+        # all-zero distances (k > distinct points): draw uniformly
+        logits = jnp.where(jnp.any(d2 > 0), jnp.log(d2), 0.0)
+        # jax.random.categorical's Gumbel-max draw, with an argmax that
+        # does not narrow to bf16 on a TPU (same draw as on the CPU)
+        g = jax.random.gumbel(jax.random.fold_in(key, i), logits.shape)
+        c = points[ref.exact_argmax(g + logits)]
+        return (cents.at[i].set(c),
+                jnp.minimum(d2, jnp.sum((points - c) ** 2, -1)))
+
+    cents, _ = jax.lax.fori_loop(1, k, body, (cents, d2))
+    return cents
 
 
 def init_codebooks(vectors: jax.Array, m: int, ksub: int, iters: int,
-                   key: jax.Array, *, backend: str = "ref") -> jax.Array:
-    """Generation-0 codebooks from a seed sample (build time)."""
+                   key: jax.Array, *, backend: str = "off") -> jax.Array:
+    """Generation-0 codebooks from a seed sample (build time):
+    k-means++ seeding per subspace, then Lloyd refinement."""
     n, d = vectors.shape
     dsub = d // m
-    idx = jax.random.choice(key, n, (ksub,), replace=n < ksub)
-    init = vectors[idx].astype(jnp.float32).reshape(
-        ksub, m, dsub).transpose(1, 0, 2)
+    pts = vectors.astype(jnp.float32).reshape(n, m, dsub).transpose(1, 0, 2)
+    init = jax.vmap(_kmeanspp, in_axes=(0, None, 0))(
+        pts, ksub, jax.random.split(key, m))
     mask = jnp.ones((n,), bool)
     return train_codebooks(vectors, mask, init, iters, backend=backend)
 
@@ -162,10 +214,20 @@ def retrain_round(state, cfg, key):
     *before* calling this round (``_promote_retrain_pinned``), and the
     training sample masks spilled rows out explicitly (their zeroed
     device rows would otherwise collapse the codebooks toward 0).
+
+    A model-sharded state runs the same three steps as separate
+    programs (``core.sharded.make_sharded_retrain``).
     """
-    from ..core.update import dataclasses_replace
+    sample, smask = retrain_sample(state, cfg, key)
+    new_cb = train_codebooks(sample, smask,
+                             state.pq_codebooks[state.pq_active],
+                             cfg.kmeans_iters, backend=cfg.use_pallas)
+    return install_codebooks(state, cfg, new_cb)
+
+
+def retrain_sample(state, cfg, key):
+    """Step (1) of :func:`retrain_round`: (sample (S, d) f32, mask (S,))."""
     M, C, d = state.vectors.shape
-    V = cfg.pq_versions
     S = cfg.pq_sample
 
     # spilled postings' device rows are zeroed (cold tier) — exclude
@@ -178,11 +240,15 @@ def retrain_round(state, cfg, key):
     keys = jax.random.uniform(key, (M * C,))
     order = jnp.argsort(jnp.where(flat_valid, keys, 2.0))[:S]
     sample = state.vectors.reshape(M * C, d)[order].astype(jnp.float32)
-    smask = flat_valid[order]
+    return sample, flat_valid[order]
 
-    active_cb = state.pq_codebooks[state.pq_active]
-    new_cb = train_codebooks(sample, smask, active_cb, cfg.kmeans_iters,
-                             backend=cfg.use_pallas)
+
+def install_codebooks(state, cfg, new_cb):
+    """Steps (3)-(4) of :func:`retrain_round`: install ``new_cb`` in the
+    oldest slot, re-encode the postings pinned to it, rotate."""
+    from ..core.update import dataclasses_replace
+    M = state.vectors.shape[0]
+    V = cfg.pq_versions
     evict = (state.pq_active + 1) % V
 
     codebooks = state.pq_codebooks.at[evict].set(new_cb)

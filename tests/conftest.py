@@ -1,8 +1,8 @@
 import os
 import sys
 
-# Tests run single-device (the dry-run owns the 512-device fake platform;
-# multi-device tests spawn subprocesses that set XLA_FLAGS themselves).
+# Tests run single-device (multi-device tests spawn subprocesses that
+# set XLA_FLAGS themselves).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np

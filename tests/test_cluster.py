@@ -412,3 +412,18 @@ def test_two_workers_stay_occupancy_balanced_on_zipf_stream():
         assert coord.live_count() == next_id
     finally:
         coord.close()
+
+
+def test_multiprocess_backend_refuses_a_tpu_host(monkeypatch):
+    """Workers inherit the parent's environment: on a TPU host each would
+    reach for the chips the parent holds, so start() fails at once, with
+    the reason, before spawning anything."""
+    import jax
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    backend = MultiProcessBackend(2)
+    with pytest.raises(RuntimeError, match="TPU host"):
+        backend.start()
+    assert backend._procs == [None, None]
+    # workers pinned to the CPU are fine on any host
+    MultiProcessBackend._check_host({"JAX_PLATFORMS": "cpu"})

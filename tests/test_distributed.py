@@ -278,3 +278,52 @@ def test_sharded_driver_end_to_end_multishard():
     assert "OK" in out
 
 
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("use_pq", [False, True])
+def test_sharded_kernels_bit_identical_to_twins(use_pq):
+    """The sharded programs with the Pallas kernels (interpret mode
+    here) answer bit-identically to the jnp twins on a 4-shard mesh:
+    insert, delete, background ticks, PQ retrains, search and exact,
+    same state."""
+    out = _run(f"""
+        import numpy as np, jax
+        from repro.api import ShardedUBISDriver
+        from repro.core import UBISConfig
+        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        # small integers: every score is exact in f32, so the kernels'
+        # summation order cannot show (as in test_e2e_d100)
+        r = np.random.default_rng(5)
+        cents = r.integers(-8, 9, (8, 16))
+        data = (cents[r.integers(0, 8, 900)]
+                + r.integers(-2, 3, (900, 16))).astype(np.float32)
+        q = (cents[r.integers(0, 8, 16)]
+             + r.integers(-2, 3, (16, 16))).astype(np.float32)
+
+        def run(use_pallas):
+            cfg = UBISConfig(dim=16, max_postings=64, capacity=32,
+                             l_min=4, l_max=24, cache_capacity=64,
+                             max_ids=1 << 10, nprobe=4,
+                             use_pq={use_pq}, pq_m=4, pq_ksub=16,
+                             rerank_k=24, use_pallas=use_pallas)
+            drv = ShardedUBISDriver(cfg, data[:200], mesh=mesh,
+                                    round_size=128, bg_ops_per_round=4,
+                                    pq_retrain_every=2)
+            drv.insert(data, np.arange(900))
+            drv.delete(np.arange(0, 900, 9))
+            drv.flush(max_ticks=8)
+            s, e = drv.search(q, 10), drv.exact(q, 10)
+            assert drv.obs.counter("kernel_fallback").value == 0.0
+            assert drv.stats["pq_retrains"] >= {int(use_pq)}
+            return (np.asarray(s.ids), np.asarray(s.scores),
+                    np.asarray(e.ids), np.asarray(e.scores),
+                    np.asarray(drv.state.id_loc))
+
+        on, off = run("on"), run("off")
+        for a, b in zip(on, off):
+            np.testing.assert_array_equal(a, b)
+        assert (on[0] >= 0).all()
+        print("OK")
+    """)
+    assert "OK" in out

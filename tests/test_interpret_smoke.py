@@ -27,7 +27,7 @@ def test_driver_workload_on_pallas_interpret(use_pq, dim, capacity, pq_m,
                                              ksub):
     cfg = UBISConfig(dim=dim, max_postings=64, capacity=capacity, l_min=8,
                      l_max=int(capacity * 0.75), cache_capacity=256,
-                     max_ids=1 << 12, nprobe=8, use_pallas="pallas",
+                     max_ids=1 << 12, nprobe=8, use_pallas="on",
                      use_pq=use_pq, pq_m=pq_m, pq_ksub=ksub, rerank_k=64)
     data = make_clustered(700, d=cfg.dim, k=5, seed=2)
     drv = UBISDriver(cfg, data[:200], round_size=128, bg_ops_per_round=4,

@@ -22,9 +22,22 @@ def test_centroid_score(Q, M, d, dtype, rng):
     q = jnp.asarray(rng.normal(size=(Q, d)), dtype)
     c = jnp.asarray(rng.normal(size=(M, d)), dtype)
     vis = jnp.asarray(rng.random(M) > 0.3)
-    a = ops.centroid_score(q, c, vis, backend="ref")
-    b = ops.centroid_score(q, c, vis, backend="pallas")
+    a = ops.centroid_score(q, c, vis, backend="off")
+    b = ops.centroid_score(q, c, vis, backend="on")
     np.testing.assert_allclose(a, b, **_tol(dtype))
+
+
+@pytest.mark.parametrize("shape,axis", [((7, 256), -1), ((40, 9), 0),
+                                        ((300,), -1)])
+def test_exact_argmin_matches_numpy_first_tie(shape, axis, rng):
+    """``ref.exact_argmin``/``exact_argmax`` pick numpy's index, the
+    lowest among ties (values drawn from a small integer set so rows
+    carry many ties)."""
+    x = rng.integers(-3, 4, size=shape).astype(np.float32)
+    np.testing.assert_array_equal(ref.exact_argmin(jnp.asarray(x), axis),
+                                  x.argmin(axis))
+    np.testing.assert_array_equal(ref.exact_argmax(jnp.asarray(x), axis),
+                                  x.argmax(axis))
 
 
 @pytest.mark.parametrize("Q,G,C,d", [(5, 3, 24, 16), (64, 8, 96, 40),
@@ -34,44 +47,9 @@ def test_posting_scan(Q, G, C, d, dtype, rng):
     q = jnp.asarray(rng.normal(size=(Q, d)), dtype)
     tiles = jnp.asarray(rng.normal(size=(G, C, d)), dtype)
     valid = jnp.asarray(rng.random((G, C)) > 0.4)
-    a = ops.posting_scan(q, tiles, valid, backend="ref")
-    b = ops.posting_scan(q, tiles, valid, backend="pallas")
+    a = ops.posting_scan(q, tiles, valid, backend="off")
+    b = ops.posting_scan(q, tiles, valid, backend="on")
     np.testing.assert_allclose(a, b, **_tol(dtype))
-
-
-@pytest.mark.parametrize("Q,M,C,P,d", [(6, 12, 128, 4, 128)])
-def test_posting_scan_gather(Q, M, C, P, d, rng):
-    q = jnp.asarray(rng.normal(size=(Q, d)).astype(np.float32))
-    vectors = jnp.asarray(rng.normal(size=(M, C, d)).astype(np.float32))
-    slot_valid = jnp.asarray(rng.random((M, C)) > 0.3)
-    vis = jnp.asarray(rng.random(M) > 0.2)
-    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
-    a = ops.posting_scan_gather(q, vectors, slot_valid, vis, probe,
-                                backend="ref")
-    b = ops.posting_scan_gather(q, vectors, slot_valid, vis, probe,
-                                backend="pallas")
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-3)
-
-
-@pytest.mark.parametrize("Q,V,m,ksub,M,C,P", [(6, 2, 8, 128, 12, 128, 4),
-                                              (3, 3, 4, 256, 9, 128, 5)])
-def test_pq_scan_gather(Q, V, m, ksub, M, C, P, rng):
-    from repro.kernels.pq_scan import pq_scan_gather as pallas_pq
-    luts = jnp.asarray(rng.normal(size=(Q, V, m, ksub)).astype(np.float32))
-    codes = jnp.asarray(rng.integers(0, ksub, (M, m, C)).astype(np.uint8))
-    slot = jnp.asarray(rng.integers(0, V, (M,)).astype(np.int32))
-    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
-    a = ref.pq_scan_gather(luts, codes, slot, probe)
-    b = pallas_pq(luts, codes, slot, probe, interpret=True)
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
-    # dispatch wrapper applies the validity mask identically per backend
-    slot_valid = jnp.asarray(rng.random((M, C)) > 0.3)
-    vis = jnp.asarray(rng.random(M) > 0.2)
-    w1 = ops.pq_scan_gather(luts, codes, slot, slot_valid, vis, probe,
-                            backend="ref")
-    w2 = ops.pq_scan_gather(luts, codes, slot, slot_valid, vis, probe,
-                            backend="pallas")
-    np.testing.assert_allclose(w1, w2, rtol=1e-5, atol=1e-5)
 
 
 def test_pq_scan_matches_decoded_float_scan(rng):
@@ -102,8 +80,8 @@ def test_kmeans_assign(N, K, d, rng):
     pts = jnp.asarray(rng.normal(size=(N, d)).astype(np.float32))
     cen = jnp.asarray(rng.normal(size=(K, d)).astype(np.float32))
     mask = jnp.asarray(rng.random(N) > 0.2)
-    a1, b1 = ops.kmeans_assign(pts, cen, mask, backend="ref")
-    a2, b2 = ops.kmeans_assign(pts, cen, mask, backend="pallas")
+    a1, b1 = ops.kmeans_assign(pts, cen, mask, backend="off")
+    a2, b2 = ops.kmeans_assign(pts, cen, mask, backend="on")
     # argmin ties can differ; compare scores, and assignments where the
     # best score is unique
     np.testing.assert_allclose(b1, b2, rtol=1e-4, atol=1e-3)
@@ -122,9 +100,9 @@ def test_flash_attention(Lq, Lk, D, Hq, Hkv, causal, window, rng):
     k = jnp.asarray(rng.normal(size=(B, Hkv, Lk, D)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(B, Hkv, Lk, D)).astype(np.float32))
     a = ops.flash_attention(q, k, v, causal=causal, window=window,
-                            backend="ref")
+                            backend="off")
     b = ops.flash_attention(q, k, v, causal=causal, window=window,
-                            backend="pallas")
+                            backend="on")
     np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
 
 
@@ -145,8 +123,8 @@ def test_centroid_topk_parity(Q, M, d, k, rng):
     q = _int_normal(rng, (Q, d))
     c = _int_normal(rng, (M, d))
     vis = jnp.asarray(rng.random(M) > 0.3)
-    s1, i1 = ops.centroid_topk(q, c, vis, k=k, backend="ref")
-    s2, i2 = ops.centroid_topk(q, c, vis, k=k, backend="pallas")
+    s1, i1 = ops.centroid_topk(q, c, vis, k=k, backend="off")
+    s2, i2 = ops.centroid_topk(q, c, vis, k=k, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -157,8 +135,8 @@ def test_centroid_topk_all_masked(rng):
     q = _int_normal(rng, (4, 8))
     c = _int_normal(rng, (12, 8))
     vis = jnp.zeros((12,), bool)
-    s1, i1 = ops.centroid_topk(q, c, vis, k=5, backend="ref")
-    s2, i2 = ops.centroid_topk(q, c, vis, k=5, backend="pallas")
+    s1, i1 = ops.centroid_topk(q, c, vis, k=5, backend="off")
+    s2, i2 = ops.centroid_topk(q, c, vis, k=5, backend="on")
     assert np.all(np.asarray(s1) >= ref.BIG / 2)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -171,8 +149,8 @@ def test_centroid_topk_ties(rng):
     base = _int_normal(rng, (8, 16))
     c = jnp.concatenate([base, base, base], axis=0)  # every score x3
     vis = jnp.ones((24,), bool)
-    s1, i1 = ops.centroid_topk(q, c, vis, k=24, backend="ref")
-    s2, i2 = ops.centroid_topk(q, c, vis, k=24, backend="pallas")
+    s1, i1 = ops.centroid_topk(q, c, vis, k=24, backend="off")
+    s2, i2 = ops.centroid_topk(q, c, vis, k=24, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -187,9 +165,9 @@ def test_posting_scan_topk_parity(Q, M, C, P, d, k, rng):
     vis = jnp.asarray(rng.random(M) > 0.2)
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     s1, i1 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, backend="ref")
+                                   k=k, backend="off")
     s2, i2 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, backend="pallas")
+                                   k=k, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -206,9 +184,9 @@ def test_posting_scan_topk_sparse_and_qp_ok(rng):
     qp_ok = jnp.asarray(rng.integers(0, 2, (Q, P)).astype(np.int32))
     k = P * C  # every candidate requested
     s1, i1 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, qp_ok=qp_ok, backend="ref")
+                                   k=k, qp_ok=qp_ok, backend="off")
     s2, i2 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, qp_ok=qp_ok, backend="pallas")
+                                   k=k, qp_ok=qp_ok, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     assert np.any(np.asarray(s1) >= ref.BIG / 2)  # sparse -> BIG tail
@@ -225,9 +203,9 @@ def test_pq_scan_topk_parity(Q, V, m, ksub, M, C, P, k, rng):
     vis = jnp.asarray(rng.random(M) > 0.2)
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     s1, i1 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="ref")
+                              k=k, backend="off")
     s2, i2 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="pallas")
+                              k=k, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
@@ -243,9 +221,9 @@ def test_pq_scan_topk_all_invalid(rng):
     vis = jnp.zeros((M,), bool)
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     s1, i1 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="ref")
+                              k=k, backend="off")
     s2, i2 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="pallas")
+                              k=k, backend="on")
     assert np.all(np.asarray(s1) >= ref.BIG / 2)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -266,29 +244,13 @@ def test_posting_scan_topk_misaligned_parity(Q, M, C, P, d, k, rng):
     vis = jnp.asarray(rng.random(M) > 0.2)
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     s1, i1 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, backend="ref")
+                                   k=k, backend="off")
     s2, i2 = ops.posting_scan_topk(q, vectors, slot_valid, vis, probe,
-                                   k=k, backend="pallas")
+                                   k=k, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     # candidate encoding uses the LOGICAL capacity C, not the padded one
     assert np.all((np.asarray(i2) >= 0) & (np.asarray(i2) < M * C))
-
-
-@pytest.mark.parametrize("Q,M,C,P,d", [(1, 5, 33, 2, 96),
-                                       (6, 12, 100, 4, 100),
-                                       (2, 8, 130, 3, 300)])
-def test_posting_scan_gather_misaligned_parity(Q, M, C, P, d, rng):
-    q = _int_normal(rng, (Q, d))
-    vectors = _int_normal(rng, (M, C, d), lo=-2, hi=3)
-    slot_valid = jnp.asarray(rng.random((M, C)) > 0.3)
-    vis = jnp.asarray(rng.random(M) > 0.2)
-    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
-    a = ops.posting_scan_gather(q, vectors, slot_valid, vis, probe,
-                                backend="ref")
-    b = ops.posting_scan_gather(q, vectors, slot_valid, vis, probe,
-                                backend="pallas")
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("Q,V,m,ksub,M,C,P,k",
@@ -303,29 +265,12 @@ def test_pq_scan_topk_misaligned_parity(Q, V, m, ksub, M, C, P, k, rng):
     vis = jnp.asarray(rng.random(M) > 0.2)
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     s1, i1 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="ref")
+                              k=k, backend="off")
     s2, i2 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
-                              k=k, backend="pallas")
+                              k=k, backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     assert np.all((np.asarray(i2) >= 0) & (np.asarray(i2) < M * C))
-
-
-@pytest.mark.parametrize("Q,V,m,ksub,M,C,P", [(1, 2, 4, 16, 6, 33, 2),
-                                              (4, 2, 4, 100, 9, 100, 4),
-                                              (2, 3, 8, 200, 7, 133, 3)])
-def test_pq_scan_gather_misaligned_parity(Q, V, m, ksub, M, C, P, rng):
-    luts = _int_normal(rng, (Q, V, m, ksub), lo=0, hi=8)
-    codes = jnp.asarray(rng.integers(0, ksub, (M, m, C)).astype(np.uint8))
-    slot = jnp.asarray(rng.integers(0, V, (M,)).astype(np.int32))
-    slot_valid = jnp.asarray(rng.random((M, C)) > 0.3)
-    vis = jnp.asarray(rng.random(M) > 0.2)
-    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
-    a = ops.pq_scan_gather(luts, codes, slot, slot_valid, vis, probe,
-                           backend="ref")
-    b = ops.pq_scan_gather(luts, codes, slot, slot_valid, vis, probe,
-                           backend="pallas")
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("Q,M,C,d,R,k", [(1, 5, 100, 96, 7, 3),
@@ -343,9 +288,9 @@ def test_rerank_topk_parity(Q, M, C, d, R, k, rng):
     adc[rng.random((Q, R)) > 0.8] = ref.BIG  # dead candidate slots
     adc = jnp.asarray(adc)
     s1, i1 = ops.rerank_topk(q, vectors, tier_spilled, cand, adc, k=k,
-                             backend="ref")
+                             backend="off")
     s2, i2 = ops.rerank_topk(q, vectors, tier_spilled, cand, adc, k=k,
-                             backend="pallas")
+                             backend="on")
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     # selected indices address the flattened (M*C) vector store
@@ -362,9 +307,9 @@ def test_rerank_topk_all_dead(rng):
     cand = jnp.asarray(rng.integers(0, M * C, (Q, R)).astype(np.int32))
     adc = jnp.full((Q, R), ref.BIG, jnp.float32)
     s1, i1 = ops.rerank_topk(q, vectors, tier_spilled, cand, adc, k=k,
-                             backend="ref")
+                             backend="off")
     s2, i2 = ops.rerank_topk(q, vectors, tier_spilled, cand, adc, k=k,
-                             backend="pallas")
+                             backend="on")
     assert np.all(np.asarray(s1) >= ref.BIG / 2)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
@@ -376,8 +321,8 @@ def test_kmeans_assign_large_nonmultiple_k(rng):
     N, K, d = 64, 200, 24
     pts = jnp.asarray(rng.normal(size=(N, d)).astype(np.float32))
     cen = jnp.asarray(rng.normal(size=(K, d)).astype(np.float32))
-    a1, b1 = ops.kmeans_assign(pts, cen, backend="ref")
-    a2, b2 = ops.kmeans_assign(pts, cen, backend="pallas")
+    a1, b1 = ops.kmeans_assign(pts, cen, backend="off")
+    a2, b2 = ops.kmeans_assign(pts, cen, backend="on")
     np.testing.assert_allclose(b1, b2, rtol=1e-4, atol=1e-3)
     assert np.all(np.asarray(a2) < K)
     same = np.asarray(a1) == np.asarray(a2)
@@ -401,12 +346,12 @@ def test_no_fallback_on_misaligned_shapes(rng):
     probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
     sig = ("test-misaligned",)
     with ops.count_fallback_dispatches(obs, sig):
-        ops.pq_scan_gather(luts, codes, slot, slot_valid, vis, probe,
-                           backend="pallas")
+        ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe, k=3,
+                         backend="on")
         q = jnp.asarray(rng.normal(size=(Q, 24)).astype(np.float32))
         vecs = jnp.asarray(rng.normal(size=(M, C, 24)).astype(np.float32))
         ops.posting_scan_topk(q, vecs, slot_valid, vis, probe, k=3,
-                              backend="pallas")
+                              backend="on")
     assert obs.counter("kernel_fallback").value == 0.0
     assert obs.counter("kernel_fallback_traces").value == 0.0
     assert obs.events("kernel_fallback") == []
@@ -420,7 +365,7 @@ def test_fallback_dispatch_counting():
     from repro.obs import Obs
     obs = Obs()
     ops.observe_fallbacks(obs)
-    sig = ("plane", "pallas", 100)
+    sig = ("plane", "on", 100)
     # first dispatch of this signature: the program "traces" and notes
     with ops.count_fallback_dispatches(obs, sig):
         ops._note_fallback("some_kernel", "no pallas lowering")
@@ -437,7 +382,7 @@ def test_fallback_dispatch_counting():
     assert obs.counter("kernel_fallback_traces").value == 3.0
     assert len(obs.events("kernel_fallback")) == 2  # one-shot per key
     # a different signature captures independently
-    with ops.count_fallback_dispatches(obs, ("plane", "pallas", 128)):
+    with ops.count_fallback_dispatches(obs, ("plane", "on", 128)):
         pass
     assert obs.counter("kernel_fallback").value == 4.0
     # reset clears the memo, the sinks and the one-shot dedup
@@ -450,13 +395,30 @@ def test_fallback_dispatch_counting():
     assert len(obs2.events("kernel_fallback")) == 1
 
 
+def test_kernel_backend_names_are_validated():
+    """``use_pallas`` and the ops ``backend`` share one spelling:
+    "auto" | "on" ("on" means the kernels) | "off" (the jnp twins).
+    Anything else raises instead of silently meaning the twins."""
+    from repro.core import UBISConfig
+    assert UBISConfig().use_pallas == "auto"
+    q = jnp.zeros((2, 8), jnp.float32)
+    np.testing.assert_array_equal(
+        ops.centroid_score(q, q, backend="on"),
+        ops.centroid_score(q, q, backend="off"))
+    for bad in ("pallas", "ref", "ON", ""):
+        with pytest.raises(ValueError, match="use_pallas"):
+            UBISConfig(use_pallas=bad)
+        with pytest.raises(ValueError, match="backend"):
+            ops.centroid_score(q, q, backend=bad)
+
+
 def test_driver_close_detaches_fallback_sink():
     """UBISDriver.close() unregisters its Obs bundle so later notes no
     longer reach it."""
     from repro.core import UBISConfig, UBISDriver
     cfg = UBISConfig(dim=16, max_postings=8, capacity=16, l_min=2,
                      l_max=12, cache_capacity=16, max_ids=1 << 8,
-                     nprobe=2, use_pallas="ref")
+                     nprobe=2, use_pallas="off")
     rng = np.random.default_rng(0)
     drv = UBISDriver(cfg, rng.normal(size=(20, 16)).astype(np.float32))
     drv.close()
@@ -476,7 +438,8 @@ def test_e2e_d100_pallas_bit_identical_zero_fallback(use_pq):
     def build(backend):
         cfg = UBISConfig(dim=100, max_postings=24, capacity=33, l_min=4,
                          l_max=28, cache_capacity=64, max_ids=1 << 11,
-                         nprobe=6, use_pallas=backend, use_pq=use_pq,
+                         nprobe=6, use_pq=use_pq,
+                         use_pallas=backend,
                          pq_m=4, pq_ksub=100, rerank_k=40)
         r = np.random.default_rng(7)
         seed = r.integers(-3, 4, (80, 100)).astype(np.float32)
@@ -488,8 +451,8 @@ def test_e2e_d100_pallas_bit_identical_zero_fallback(use_pq):
         q = r.integers(-3, 4, (5, 100)).astype(np.float32)
         return drv, drv.search(q, 10)
 
-    drv_p, res_p = build("pallas")
-    _, res_r = build("ref")
+    drv_p, res_p = build("on")
+    _, res_r = build("off")
     np.testing.assert_array_equal(np.asarray(res_p.ids),
                                   np.asarray(res_r.ids))
     np.testing.assert_array_equal(np.asarray(res_p.scores),

@@ -293,3 +293,22 @@ def test_profile_hook_is_best_effort(tmp_path):
     with obs.profile(str(tmp_path / "prof")):
         ran.append(2)
     assert ran == [1, 2]
+
+
+def test_profile_raises_when_the_trace_cannot_start(tmp_path, monkeypatch):
+    """A trace directory was asked for: a capture that cannot start
+    must not degrade to an untraced run."""
+    import jax
+
+    def refuse(*a, **k):
+        raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    ran = []
+    with pytest.raises(RuntimeError, match="profiler busy"):
+        with Obs().profile(str(tmp_path / "prof")):
+            ran.append(1)
+    assert ran == []
+    with Obs().profile(None):                  # no dir asked: no trace
+        ran.append(2)
+    assert ran == [2]

@@ -8,7 +8,7 @@ import pytest
 from repro.core import (UBISConfig, UBISDriver, brute_force, metrics,
                         version_manager as vm)
 from repro.core.search import search as search_fn
-from repro.kernels import ops
+from repro.kernels import ops, ref
 from repro.kernels.posting_scan import BIG
 from repro.quant import pq
 from conftest import make_clustered
@@ -70,6 +70,23 @@ def test_codes_track_floats_through_churn(mode):
     assert_codes_in_sync(drv.state, drv.cfg)
 
 
+def test_encode_in_row_blocks_matches_one_pass(monkeypatch):
+    """``pq.encode`` over more than ``ENCODE_ROWS`` rows (blocked, the
+    last block padded) equals one unblocked pass and a float64 numpy
+    nearest-centroid encode."""
+    r = np.random.default_rng(4)
+    cb = jnp.asarray(r.normal(size=(4, 32, 4)), jnp.float32)
+    x = jnp.asarray(r.normal(size=(300, 16)), jnp.float32)
+    one = pq.encode(cb, x)
+    monkeypatch.setattr(pq, "ENCODE_ROWS", 64)
+    np.testing.assert_array_equal(pq.encode(cb, x), one)
+    c = np.asarray(cb, np.float64)
+    xs = np.asarray(x, np.float64).reshape(300, 4, 4)
+    sc = (np.einsum("jkd,jkd->jk", c, c)[None]
+          - 2 * np.einsum("njd,jkd->njk", xs, c))
+    np.testing.assert_array_equal(np.asarray(one), sc.argmin(-1))
+
+
 def test_decode_reencode_fixed_point():
     """Decode -> nearest-centroid re-encode is a fixed point (decoded
     vectors quantize back to their own code)."""
@@ -113,9 +130,9 @@ def test_use_pq_false_is_bit_identical_to_float_path():
                          state.global_version)
         csc = ops.centroid_score(q32, state.centroids, vis, backend="off")
         _, probe = jax.lax.top_k(-csc, nprobe)
-        pscores = ops.posting_scan_gather(
+        pscores = ref.posting_scan_gather(
             q32, state.vectors, state.slot_valid, vis,
-            probe.astype(jnp.int32), backend="off")
+            probe.astype(jnp.int32))
         pids = state.ids[probe]
         cscores = ops.centroid_score(q32, state.cache_vecs,
                                      state.cache_valid, backend="off")
