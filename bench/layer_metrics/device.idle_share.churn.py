@@ -1,0 +1,8 @@
+"""Device layer: the share of the window in which no operation ran
+on the chip, 100 * (1 - busy / window)."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
