@@ -10,6 +10,8 @@ single exposition covers every layer:
   move, and PQ retrain states its reason;
 * request spans (queue wait, service, end-to-end latency) from the
   serving engine;
+* program spans: the tick and each of its phases, inserts, searches
+  and every blocking device->host read (``span_*_seconds``);
 * the sampled live-recall probe, shadow-executing 25% of served query
   batches against ``exact()``.
 
@@ -73,6 +75,18 @@ def main(profile_dir=None):
     lat = engine.obs.snapshot()["serve_latency_seconds"]
     print(f"== request spans == n={lat['count']} "
           f"p50={lat['p50']*1e3:.2f}ms p99={lat['p99']*1e3:.2f}ms")
+
+    snap = engine.obs.snapshot()
+    print("== program spans ==")
+    for name in ("tick", "tick.execute", "tick.drain", "tick.mark",
+                 "tick.gc", "insert", "search.collect"):
+        h = snap["span_" + name.replace(".", "_") + "_seconds"]
+        print(f"  {name:15s} n={h['count']:3d} "
+              f"mean={h['mean'] * 1e3:.2f}ms")
+    syncs = sum(v["count"] for k, v in snap.items()
+                if k.startswith("span_sync_"))
+    print(f"  host syncs: {syncs} "
+          f"({snap['span_tick_seconds']['count']} ticks)")
 
     evs = list(engine.obs.events())
     print(f"== trace ring: {len(evs)} events ==")

@@ -38,7 +38,7 @@ _DRIVER_KW = frozenset({
     "seed", "round_size", "bg_ops_per_round", "drain_per_tick",
     "insert_retries", "gc_lag", "reassign_after_split",
     "pq_retrain_every", "tier_moves_per_tick", "tier_rerank_host",
-    "tier_async", "obs", "obs_profile_dir"})
+    "tier_async", "obs"})
 _UBIS_KW = _DRIVER_KW | {"fused_tick"}
 _SHARDED_KW = _DRIVER_KW | {"mesh", "shard_cache_scan", "rebalance",
                             "rebalance_watermark", "rebalance_ratio",

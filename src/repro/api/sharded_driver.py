@@ -104,8 +104,7 @@ class ShardedUBISDriver:
                  tier_moves_per_tick: int = 32,
                  tier_rerank_host: bool = True,
                  tier_async: bool = False,
-                 obs: Optional[Obs] = None,
-                 obs_profile_dir: Optional[str] = None):
+                 obs: Optional[Obs] = None):
         if not cfg.is_ubis:
             raise ValueError("ShardedUBISDriver is UBIS-mode only "
                              "(SPFresh's lock model is single-device)")
@@ -133,8 +132,6 @@ class ShardedUBISDriver:
         self.obs = obs if obs is not None else Obs()
         ops.observe_fallbacks(self.obs)
         self.stats = self.obs.driver_stats()
-        self._profile_dir = obs_profile_dir
-        self._profiled = False
 
         specs = index_specs(cfg)
         self._shardings = jax.tree_util.tree_map(
@@ -369,13 +366,6 @@ class ShardedUBISDriver:
         select/mark/execute/GC program (which also reports per-shard
         pressure), then the cross-shard rebalance stage, then the host
         cache drain, then the PQ re-train on cadence."""
-        if self._profile_dir and not self._profiled:
-            self._profiled = True
-            with self.obs.profile(self._profile_dir):
-                return self._tick_impl()
-        return self._tick_impl()
-
-    def _tick_impl(self) -> TickReport:
         t0 = time.perf_counter()
         plan = None
         if self.tier is not None and self.tier_async:
@@ -430,7 +420,7 @@ class ShardedUBISDriver:
     # The cluster worker (``repro.cluster.worker``) drives these pieces
     # directly: observations (pressure, plan inputs) ship up to the
     # coordinator, plans (migrate moves, retrain slot, tier lanes) ship
-    # back down, and ``_tick_impl`` above is just the in-process
+    # back down, and ``tick`` above is just the in-process
     # composition of the same halves — one code path, two deployments.
 
     def exec_background(self):

@@ -9,7 +9,7 @@ and drives workers through the serializable command protocol
 (``cluster.protocol``).
 
 **The tick** is three legs per worker, preserving the in-process
-``ShardedUBISDriver._tick_impl`` mutation order exactly:
+``ShardedUBISDriver.tick`` mutation order exactly:
 
   1. ``tick_begin``  — worker runs the sharded background program and
      ships pressure rows up; the coordinator's rebalance planner gates

@@ -80,8 +80,7 @@ class UBISDriver:
                  tier_moves_per_tick: int = 32,
                  tier_rerank_host: bool = True,
                  tier_async: bool = False,
-                 obs: Optional[Obs] = None,
-                 obs_profile_dir: Optional[str] = None):
+                 obs: Optional[Obs] = None):
         self.cfg = cfg
         self.round_size = int(round_size)
         self.bg_ops = int(bg_ops_per_round)
@@ -94,10 +93,6 @@ class UBISDriver:
         # it, so every engine exposes the same key set
         self.obs = obs if obs is not None else Obs()
         ops.observe_fallbacks(self.obs)
-        # opt-in jax.profiler capture: the FIRST tick after construction
-        # is wrapped in a device trace written under this directory
-        self._profile_dir = obs_profile_dir
-        self._profiled = False
         # quant plane: codebook re-train cadence in ticks (0 = never);
         # only meaningful with cfg.use_pq
         self.pq_retrain_every = int(pq_retrain_every)
@@ -148,49 +143,53 @@ class UBISDriver:
                              f"{len(ids)}")
         if ids.size and (ids.min() < 0 or ids.max() >= self.cfg.max_ids):
             raise ValueError("ids out of range for cfg.max_ids")
-        t0 = time.perf_counter()
         n_acc = n_cache = n_rej = 0
         J = self.round_size
-        pending = (vecs, ids, np.full(ids.shape, -1, np.int32))
-        for attempt in range(self.retries + 1):
-            pv, pi, ph = pending
-            rej_v, rej_i, rej_h = [], [], []
-            for off in range(0, len(pi), J):
-                cv, ci, ch = pv[off:off + J], pi[off:off + J], ph[off:off + J]
-                pad = J - len(ci)
-                valid = np.concatenate([np.ones(len(ci), bool),
-                                        np.zeros(pad, bool)])
-                cv = np.concatenate([cv, np.zeros((pad, self.cfg.dim),
-                                                  np.float32)])
-                ci = np.concatenate([ci, np.zeros(pad, np.int32)])
-                ch = np.concatenate([ch, np.full(pad, -1, np.int32)])
-                self.state, res, _touched = update.insert_round(
-                    self.state, self.cfg, jnp.asarray(cv), jnp.asarray(ci),
-                    jnp.asarray(valid), jnp.asarray(ch))
-                acc, cac, rej = (np.asarray(res.accepted),
-                                 np.asarray(res.cached),
-                                 np.asarray(res.rejected))
-                n_acc += int(acc.sum())
-                n_cache += int(cac.sum())
-                if self.tier is not None:       # appends heat their target
-                    self.tier.note_targets(np.asarray(res.target)[acc])
-                if rej.any():
-                    rej_v.append(cv[rej])
-                    rej_i.append(ci[rej])
-                    rej_h.append(np.full(int(rej.sum()), -1, np.int32))
-                if not self.cfg.is_ubis:
-                    self._note_spfresh_overflow(np.asarray(res.target)[acc])
-            if not rej_v:
-                pending = None
-                break
-            pending = (np.concatenate(rej_v), np.concatenate(rej_i),
-                       np.concatenate(rej_h))
-            if tick_between:
-                self.tick()
-        if pending is not None:
-            n_rej = len(pending[1])
-        jax.block_until_ready(self.state.lengths)
-        dt = time.perf_counter() - t0
+        # the round's target ids are read only where something notes them
+        want_target = self.tier is not None or not self.cfg.is_ubis
+        with self.obs.span("insert") as sp:
+            pending = (vecs, ids, np.full(ids.shape, -1, np.int32))
+            for attempt in range(self.retries + 1):
+                pv, pi, ph = pending
+                rej_v, rej_i, rej_h = [], [], []
+                for off in range(0, len(pi), J):
+                    cv, ci, ch = (pv[off:off + J], pi[off:off + J],
+                                  ph[off:off + J])
+                    pad = J - len(ci)
+                    valid = np.concatenate([np.ones(len(ci), bool),
+                                            np.zeros(pad, bool)])
+                    cv = np.concatenate([cv, np.zeros((pad, self.cfg.dim),
+                                                      np.float32)])
+                    ci = np.concatenate([ci, np.zeros(pad, np.int32)])
+                    ch = np.concatenate([ch, np.full(pad, -1, np.int32)])
+                    self.state, res, _touched = update.insert_round(
+                        self.state, self.cfg, jnp.asarray(cv),
+                        jnp.asarray(ci), jnp.asarray(valid), jnp.asarray(ch))
+                    acc, cac, rej, *tgt = self.obs.fetch(
+                        "insert", res.accepted, res.cached, res.rejected,
+                        *([res.target] if want_target else []))
+                    n_acc += int(acc.sum())
+                    n_cache += int(cac.sum())
+                    if self.tier is not None:   # appends heat their target
+                        self.tier.note_targets(tgt[0][acc])
+                    if rej.any():
+                        rej_v.append(cv[rej])
+                        rej_i.append(ci[rej])
+                        rej_h.append(np.full(int(rej.sum()), -1, np.int32))
+                    if not self.cfg.is_ubis:
+                        self._note_spfresh_overflow(tgt[0][acc])
+                if not rej_v:
+                    pending = None
+                    break
+                pending = (np.concatenate(rej_v), np.concatenate(rej_i),
+                           np.concatenate(rej_h))
+                if tick_between:
+                    self.tick()
+            if pending is not None:
+                n_rej = len(pending[1])
+            with self.obs.span("sync.insert"):
+                jax.block_until_ready(self.state.lengths)
+        dt = sp.seconds
         self.stats["insert_time"] += dt
         self.stats["inserted"] += n_acc + n_cache
         self.stats["rejected"] += n_rej
@@ -201,21 +200,23 @@ class UBISDriver:
 
     def delete(self, ids) -> UpdateResult:
         ids = np.asarray(ids, np.int64).astype(np.int32)
-        t0 = time.perf_counter()
         J = self.round_size
         n_done = n_blocked = 0
-        for off in range(0, len(ids), J):
-            ci = ids[off:off + J]
-            pad = J - len(ci)
-            valid = np.concatenate([np.ones(len(ci), bool),
-                                    np.zeros(pad, bool)])
-            ci = np.concatenate([ci, np.zeros(pad, np.int32)])
-            self.state, done, blocked = update.delete_round(
-                self.state, self.cfg, jnp.asarray(ci), jnp.asarray(valid))
-            n_done += int(np.asarray(done).sum())
-            n_blocked += int(np.asarray(blocked).sum())
-        jax.block_until_ready(self.state.lengths)
-        dt = time.perf_counter() - t0
+        with self.obs.span("delete") as sp:
+            for off in range(0, len(ids), J):
+                ci = ids[off:off + J]
+                pad = J - len(ci)
+                valid = np.concatenate([np.ones(len(ci), bool),
+                                        np.zeros(pad, bool)])
+                ci = np.concatenate([ci, np.zeros(pad, np.int32)])
+                self.state, done, blocked = update.delete_round(
+                    self.state, self.cfg, jnp.asarray(ci), jnp.asarray(valid))
+                done, blocked = self.obs.fetch("delete", done, blocked)
+                n_done += int(done.sum())
+                n_blocked += int(blocked.sum())
+            with self.obs.span("sync.delete"):
+                jax.block_until_ready(self.state.lengths)
+        dt = sp.seconds
         self.stats["delete_time"] += dt
         self.stats["deleted"] += n_done
         self.stats["blocked"] += n_blocked
@@ -233,58 +234,62 @@ class UBISDriver:
         dispatch: the call returns as soon as the program is enqueued).
         The serving engine overlaps inserts/ticks here; pair with
         ``collect_search``."""
-        queries = np.asarray(queries, np.float32)
-        t0 = time.perf_counter()
-        # host rerank widens the final candidate set to rerank_k (the
-        # device top-k orders spilled candidates by ADC score, so the
-        # exact host pass must see the full rerank budget to matter —
-        # cutting this below rerank_k measurably costs recall on a
-        # mostly-cold index)
-        k_eff = (max(k, self.cfg.rerank_k)
-                 if self.tier is not None and self.tier.rerank_host
-                 else k)
-        # per-dispatch fallback accounting: the signature carries every
-        # routing decision (backend knob + plane shape); the query batch
-        # size is deliberately omitted — re-traces of the same signature
-        # route identically (see ops.count_fallback_dispatches)
-        sig = ("ubis-search", self.cfg.use_pallas, self.cfg.dim,
-               self.cfg.capacity, self.cfg.use_pq, self.cfg.pq_ksub)
-        with ops.count_fallback_dispatches(self.obs, sig):
-            found, scores, probe = search_mod.search(
-                self.state, self.cfg, jnp.asarray(queries), k_eff, nprobe)
-        return SearchDispatch(state=self.state, queries=queries, k=k,
-                              found=found, scores=scores, probe=probe,
-                              t0=t0)
+        with self.obs.span("search.dispatch"):
+            queries = np.asarray(queries, np.float32)
+            t0 = time.perf_counter()
+            # host rerank widens the final candidate set to rerank_k
+            # (the device top-k orders spilled candidates by ADC score,
+            # so the exact host pass must see the full rerank budget to
+            # matter — cutting this below rerank_k measurably costs
+            # recall on a mostly-cold index)
+            k_eff = (max(k, self.cfg.rerank_k)
+                     if self.tier is not None and self.tier.rerank_host
+                     else k)
+            # per-dispatch fallback accounting: the signature carries
+            # every routing decision (backend knob + plane shape); the
+            # query batch size is deliberately omitted — re-traces of the
+            # same signature route identically (see
+            # ops.count_fallback_dispatches)
+            sig = ("ubis-search", self.cfg.use_pallas, self.cfg.dim,
+                   self.cfg.capacity, self.cfg.use_pq, self.cfg.pq_ksub)
+            with ops.count_fallback_dispatches(self.obs, sig):
+                found, scores, probe = search_mod.search(
+                    self.state, self.cfg, jnp.asarray(queries), k_eff,
+                    nprobe)
+            return SearchDispatch(state=self.state, queries=queries, k=k,
+                                  found=found, scores=scores, probe=probe,
+                                  t0=t0)
 
     def collect_search(self, disp: SearchDispatch) -> SearchResult:
         """Await a dispatched search and finish the host-side tail
         (heat notes, tier rerank, stats) against the dispatch-time
         state."""
-        found = np.asarray(disp.found)
-        scores = np.asarray(disp.scores)
-        probe = np.asarray(disp.probe)
-        if self.tier is not None:
-            # probes are the search-heat signal (promote trigger), and
-            # spilled candidates in the final candidate set get their
-            # true distance from the pinned pool (optional host rerank)
-            self.tier.note_probes(probe)
-            found, scores = self.tier.rerank(disp.state, disp.queries,
-                                             found, scores)
-            found, scores = found[:, :disp.k], scores[:, :disp.k]
-        dt = time.perf_counter() - disp.t0
-        self.stats["search_time"] += dt
-        self.stats["queries"] += disp.queries.shape[0]
-        # search introspection, piggybacked on arrays the result path
-        # already transferred (no added device syncs)
-        self.stats["search_probed"] += int((probe >= 0).sum())
-        self.stats["search_results"] += int((found >= 0).sum())
-        if self.cfg.use_pq:
-            self.stats["search_adc_batches"] += 1
-        else:
-            self.stats["search_exact_batches"] += 1
-        if not self.cfg.is_ubis:
-            self._note_spfresh_small(probe)
-        return SearchResult(ids=found, scores=scores, seconds=dt)
+        with self.obs.span("search.collect"):
+            found, scores, probe = self.obs.fetch("search", disp.found,
+                                                  disp.scores, disp.probe)
+            if self.tier is not None:
+                # probes are the search-heat signal (promote trigger),
+                # and spilled candidates in the final candidate set get
+                # their true distance from the pinned pool (optional host
+                # rerank)
+                self.tier.note_probes(probe)
+                found, scores = self.tier.rerank(disp.state, disp.queries,
+                                                 found, scores)
+                found, scores = found[:, :disp.k], scores[:, :disp.k]
+            dt = time.perf_counter() - disp.t0
+            self.stats["search_time"] += dt
+            self.stats["queries"] += disp.queries.shape[0]
+            # search introspection, piggybacked on arrays the result path
+            # already transferred (no added device syncs)
+            self.stats["search_probed"] += int((probe >= 0).sum())
+            self.stats["search_results"] += int((found >= 0).sum())
+            if self.cfg.use_pq:
+                self.stats["search_adc_batches"] += 1
+            else:
+                self.stats["search_exact_batches"] += 1
+            if not self.cfg.is_ubis:
+                self._note_spfresh_small(probe)
+            return SearchResult(ids=found, scores=scores, seconds=dt)
 
     # ------------------------------------------------------------------
     # background
@@ -294,41 +299,39 @@ class UBISDriver:
         """One background round: execute marked ops, drain the cache,
         detect + mark new candidates, GC, (quant plane) re-train the PQ
         codebooks on cadence, and (cold tier) run the spill/promote
-        planner."""
-        if self._profile_dir and not self._profiled:
-            self._profiled = True
-            with self.obs.profile(self._profile_dir):
-                return self._tick_impl()
-        return self._tick_impl()
-
-    def _tick_impl(self) -> TickReport:
-        t0 = time.perf_counter()
-        plan = None
-        if self.tier is not None and self.tier_async:
-            # tick-start dispatch: the spill tiles' D2H copy and the
-            # promote tiles' H2D staging run while the background round
-            # executes below; reconcile validates + commits at tick end.
-            # Whether the round will carry the decay is known now — the
-            # marked batch was chosen LAST tick.
-            will_decay = (self._marked_dev is not None if self.fused_tick
-                          else bool(self._marked))
-            self.state, plan = self.tier.dispatch(self.state,
-                                                  decayed=will_decay)
-        executed = self._execute_marked()
-        self.stats["bg_exec_time"] += time.perf_counter() - t0
-        drained = self._drain_cache() if self.cfg.is_ubis else 0
-        marked = self._mark_candidates()
-        reclaimed = self._gc()
-        retrained = self._pq_retrain()
-        if self.tier is not None and self.tier_async:
-            self.state, n_s, n_p = self.tier.reconcile(self.state, plan)
-            self.stats["tier_spilled"] += n_s
-            self.stats["tier_promoted"] += n_p
-            self.stats["tier_resident"] = len(self.tier.pool)
-            spilled, promoted = n_s, n_p
-        else:
-            spilled, promoted = self._tier_step()
-        dt = time.perf_counter() - t0
+        planner.  Each phase runs in its own ``tick.*`` span."""
+        span = self.obs.span
+        drained = retrained = spilled = promoted = 0
+        with span("tick") as sp:
+            with span("tick.execute") as sp_exec:
+                plan = None
+                if self.tier is not None and self.tier_async:
+                    # tick-start dispatch: the spill tiles' D2H copy and
+                    # the promote tiles' H2D staging run while the
+                    # background round executes below; reconcile
+                    # validates + commits at tick end.  Whether the round
+                    # will carry the decay is known now — the marked
+                    # batch was chosen LAST tick.
+                    will_decay = (self._marked_dev is not None
+                                  if self.fused_tick else bool(self._marked))
+                    self.state, plan = self.tier.dispatch(
+                        self.state, decayed=will_decay)
+                executed = self._execute_marked()
+            self.stats["bg_exec_time"] += sp_exec.seconds
+            if self.cfg.is_ubis:
+                with span("tick.drain"):
+                    drained = self._drain_cache()
+            with span("tick.mark"):
+                marked = self._mark_candidates()
+            with span("tick.gc"):
+                reclaimed = self._gc()
+            if self._pq_retrain_due():
+                with span("tick.retrain"):
+                    retrained = self._pq_retrain()
+            if self.tier is not None:
+                with span("tick.tier"):
+                    spilled, promoted = self._tier_step(plan)
+        dt = sp.seconds
         self.stats["bg_time"] += dt
         self.stats["bg_ops"] += executed
         self.stats["bg_gc"] += reclaimed
@@ -391,7 +394,7 @@ class UBISDriver:
             self.state, self.cfg, kinds, pids,
             reassign=self.reassign_after_split)
         self._bg_ran = True        # the round carried the heat decay
-        rr = jax.device_get(rr)
+        rr = self.obs.fetch("round", rr)
         self.stats["bg_split"] += int(rr.n_split)
         self.stats["bg_merge"] += int(rr.n_merge)
         self.stats["bg_compact"] += int(rr.n_compact)
@@ -405,7 +408,7 @@ class UBISDriver:
         return int(rr.executed)
 
     def _drain_cache(self) -> int:
-        cache_n = int(jnp.sum(self.state.cache_valid))
+        cache_n = int(self.obs.fetch("drain", jnp.sum(self.state.cache_valid)))
         if cache_n == 0:
             return 0
         n = min(self.drain_n, self.round_size)
@@ -418,7 +421,7 @@ class UBISDriver:
         taken = jnp.pad(taken, (0, pad))
         self.state, res, _ = update.insert_round(
             self.state, self.cfg, vecs, ids, taken, targets)
-        return int(jnp.sum(res.accepted))
+        return int(self.obs.fetch("drain", jnp.sum(res.accepted)))
 
     def _mark_candidates(self) -> int:
         from .types import STATUS_MERGING, STATUS_SPLITTING
@@ -428,7 +431,7 @@ class UBISDriver:
             # device — only the scalar count does, for flush quiescence
             self.state, kinds, pids, n = balance.mark_round(
                 self.state, self.cfg, self.bg_ops)
-            n = int(n)
+            n = int(self.obs.fetch("detect", n))
             self._marked_dev = (kinds, pids) if n else None
             if n:
                 # pids stay on device by design — only the count leaves
@@ -436,9 +439,9 @@ class UBISDriver:
                               marked=n)
             return n
         if self.cfg.is_ubis:
-            split_due, merge_due, compact_due = jax.device_get(
-                balance.detect(self.state, self.cfg))
-            lengths = np.asarray(self.state.lengths)
+            split_due, merge_due, compact_due, lengths = self.obs.fetch(
+                "detect", *balance.detect(self.state, self.cfg),
+                self.state.lengths)
             split_pids = np.flatnonzero(split_due)
             split_pids = split_pids[np.argsort(-lengths[split_pids])]
             merge_pids = np.flatnonzero(merge_due)
@@ -446,13 +449,13 @@ class UBISDriver:
             compact_pids = np.flatnonzero(compact_due)
         else:
             from . import version_manager as vm_
-            lengths = np.asarray(self.state.lengths)
-            alloc = np.asarray(self.state.allocated)
             # candidates were noted at search/insert time; a posting may
             # have been retired since — marking a DELETED posting would
             # RESURRECT its stale tile (duplicate vectors), so require
             # NORMAL status now (found by the invariant property test)
-            status = np.asarray(vm_.unpack_status(self.state.rec_meta))
+            lengths, alloc, status = self.obs.fetch(
+                "detect", self.state.lengths, self.state.allocated,
+                vm_.unpack_status(self.state.rec_meta))
             normal = alloc & (status == 0)
             split_pids = np.array(
                 [p for p in self._sp_split
@@ -505,29 +508,33 @@ class UBISDriver:
         return len(jobs)
 
     def _gc(self) -> int:
-        ver = int(self.state.global_version)
+        ver = int(self.obs.fetch("gc", self.state.global_version))
         if ver <= self.gc_lag:
             return 0
         self.state, n = balance.gc_round(
             self.state, self.cfg, jnp.uint32(ver - self.gc_lag), 64)
-        return int(n)
+        return int(self.obs.fetch("gc", n))
+
+    def _pq_retrain_due(self) -> bool:
+        """Count one tick of the codebook re-train cadence; True when
+        this tick re-trains (quant plane only)."""
+        if not self.cfg.use_pq or self.pq_retrain_every <= 0:
+            return False
+        self._ticks += 1
+        return self._ticks % self.pq_retrain_every == 0
 
     def _pq_retrain(self) -> int:
-        """Versioned codebook re-train on tick cadence (quant plane)."""
-        if not self.cfg.use_pq or self.pq_retrain_every <= 0:
-            return 0
-        self._ticks += 1
-        if self._ticks % self.pq_retrain_every:
-            return 0
+        """Versioned codebook re-train (quant plane)."""
         from ..quant import pq
         self._promote_retrain_pinned()
-        evict = (int(self.state.pq_active) + 1) % self.cfg.pq_versions
+        evict = (int(self.obs.fetch("retrain", self.state.pq_active)) + 1
+                 ) % self.cfg.pq_versions
         self._pq_key, k = jax.random.split(self._pq_key)
         self.state = pq.retrain_round(self.state, self.cfg, k)
         self.stats["pq_retrains"] += 1
         # live codebook generation, for monitors (throughput() readers)
-        self.stats["pq_generation"] = int(
-            self.state.pq_slot_gen[self.state.pq_active])
+        self.stats["pq_generation"] = int(self.obs.fetch(
+            "retrain", self.state.pq_slot_gen[self.state.pq_active]))
         self.obs.emit("pq_retrain", reason="cadence",
                       evicted_slot=evict,
                       generation=int(self.stats["pq_generation"]))
@@ -542,13 +549,15 @@ class UBISDriver:
         self.state, n = self.tier.promote_retrain_pinned(self.state)
         self.stats["tier_promoted"] += n
 
-    def _tier_step(self) -> tuple:
-        """Cold-tier plane: apply accumulated touches, run the
-        spill/promote planner, execute the moves."""
-        if self.tier is None:
-            return 0, 0
-        self.state, n_s, n_p = self.tier.tick(self.state,
-                                              decayed=self._bg_ran)
+    def _tier_step(self, plan) -> tuple:
+        """Cold-tier plane: reconcile the moves dispatched at tick start
+        (``tier_async``), or apply accumulated touches, run the
+        spill/promote planner and execute the moves."""
+        if self.tier_async:
+            self.state, n_s, n_p = self.tier.reconcile(self.state, plan)
+        else:
+            self.state, n_s, n_p = self.tier.tick(self.state,
+                                                  decayed=self._bg_ran)
         self.stats["tier_spilled"] += n_s
         self.stats["tier_promoted"] += n_p
         self.stats["tier_resident"] = len(self.tier.pool)
@@ -576,13 +585,13 @@ class UBISDriver:
     # ---- SPFresh strict-trigger bookkeeping ---------------------------
 
     def _note_spfresh_overflow(self, pids: np.ndarray):
-        lengths = np.asarray(self.state.lengths)
+        lengths = self.obs.fetch("insert", self.state.lengths)
         for p in np.unique(pids):
             if p >= 0 and lengths[p] > self.cfg.l_max:
                 self._sp_split.add(int(p))
 
     def _note_spfresh_small(self, probe: np.ndarray):
-        lengths = np.asarray(self.state.lengths)
+        lengths = self.obs.fetch("search", self.state.lengths)
         small = np.unique(probe[lengths[probe] < self.cfg.l_min])
         for p in small:
             if p >= 0:

@@ -46,7 +46,8 @@ class ServeConfig:
     # (0 = never; the old server ticked unconditionally per ingest)
     tick_every: int = 1
     # observability plane: sampled live-recall probe fraction, optional
-    # JSONL trace sink, optional jax.profiler capture directory
+    # JSONL trace sink, optional jax.profiler capture directory (the
+    # serving engine's first working pump)
     recall_probe: float = 0.0
     obs_trace_path: Optional[str] = None
     obs_profile_dir: Optional[str] = None
@@ -110,9 +111,7 @@ class RetrievalServer:
         self.obs = engine_kw.pop("obs", None) or Obs(
             trace_path=cfg.obs_trace_path)
         self.index = make_index(engine, index_cfg, seed_vectors,
-                                obs=self.obs,
-                                obs_profile_dir=cfg.obs_profile_dir,
-                                **engine_kw)
+                                obs=self.obs, **engine_kw)
         if serving_cfg is None:
             serving_cfg = ServingConfig(default_k=cfg.k,
                                         tick_every=cfg.tick_every,
@@ -208,8 +207,8 @@ def main(argv=None):
     ap.add_argument("--obs-trace-path", default=None,
                     help="append structured trace events to this JSONL file")
     ap.add_argument("--obs-profile-dir", default=None,
-                    help="capture a jax.profiler trace of the first "
-                         "working pump/tick into this directory")
+                    help="capture a jax.profiler trace of the serving "
+                         "engine's first working pump into this directory")
     ap.add_argument("--metrics", action="store_true",
                     help="print the Prometheus exposition at exit")
     args = ap.parse_args(argv)

@@ -11,12 +11,18 @@ One :class:`Obs` object bundles the three planes every layer shares:
   with reasons.
 * :class:`~repro.obs.probe.RecallProbe` — sampled live-recall probe
   (built by the serving engine on demand via :meth:`Obs.make_probe`).
+* :meth:`Obs.span` — a named block (:data:`SPAN_NAMES`) timed into a
+  ``span_<name>_seconds`` histogram and annotated for ``jax.profiler``;
+  :meth:`Obs.fetch` is the driver's one device->host read, inside a
+  ``sync.<site>`` span.
 
 Drivers default-construct an ``Obs()`` when none is injected; the
 serving engine reuses its index's plane so one exposition covers driver
 internals and request spans.  ``Obs(enabled=False)`` keeps the stats
 mapping (the drivers need it) but turns tracing and span recording into
-no-ops — that delta is what the figserve obs-overhead row measures.
+no-ops — that delta is what the figserve obs-overhead row measures.  Its
+spans still time their block (the stats' ``*_time`` keys read them) but
+record nothing and annotate nothing.
 """
 from __future__ import annotations
 
@@ -24,16 +30,19 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Optional
 
+import jax
+
 from .metrics import (DRIVER_STAT_SCHEMA, GAUGE_STAT_KEYS, Counter, Gauge,
                       Histogram, MetricsRegistry, StatsMap, parse_exposition,
                       required_series)
 from .probe import RecallProbe
+from .span import PREFIX as SPAN_PREFIX, SPAN_NAMES, Span, histogram_name
 from .trace import Tracer
 
 __all__ = [
     "Obs", "MetricsRegistry", "Tracer", "RecallProbe", "Counter", "Gauge",
-    "Histogram", "StatsMap", "DRIVER_STAT_SCHEMA", "GAUGE_STAT_KEYS",
-    "parse_exposition", "required_series",
+    "Histogram", "StatsMap", "Span", "DRIVER_STAT_SCHEMA", "GAUGE_STAT_KEYS",
+    "SPAN_NAMES", "SPAN_PREFIX", "parse_exposition", "required_series",
 ]
 
 
@@ -48,6 +57,7 @@ class Obs:
         self.registry = MetricsRegistry()
         self.tracer = Tracer(capacity=trace_capacity, path=trace_path,
                              clock=clock, enabled=enabled)
+        self._span_hists: dict = {}
 
     # ---- construction passthrough ------------------------------------
 
@@ -76,6 +86,30 @@ class Obs:
     def events(self, kind: Optional[str] = None):
         return self.tracer.events(kind)
 
+    # ---- spans and device reads ---------------------------------------
+
+    def span(self, name: str) -> Span:
+        """A context manager timing one block of :data:`SPAN_NAMES`;
+        its ``seconds`` hold the duration once it exits."""
+        if not self.enabled:
+            return Span(self.tracer.clock)
+        hist = self._span_hists.get(name)
+        if hist is None:
+            if name not in SPAN_NAMES:
+                raise ValueError(f"unknown span {name!r} (not in "
+                                 "repro.obs.SPAN_NAMES)")
+            hist = self._span_hists[name] = self.registry.histogram(
+                histogram_name(name))
+        return Span(self.tracer.clock, hist, SPAN_PREFIX + name)
+
+    def fetch(self, site: str, *arrays):
+        """The one blocking device->host read: ``jax.device_get`` of
+        ``arrays`` together, inside span ``sync.<site>``.  Returns the
+        host value of a single array, else a tuple."""
+        with self.span("sync." + site):
+            out = jax.device_get(arrays)
+        return out[0] if len(arrays) == 1 else out
+
     # ---- export -------------------------------------------------------
 
     def snapshot(self):
@@ -95,7 +129,6 @@ class Obs:
         if not trace_dir:
             yield
             return
-        import jax
         jax.profiler.start_trace(str(trace_dir))
         try:
             yield

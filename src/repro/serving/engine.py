@@ -116,12 +116,11 @@ class ServingEngine:
         self.obs = (obs if obs is not None
                     else getattr(index, "obs", None) or Obs())
         # request-span histograms (engine-clock seconds): queue wait
-        # (submit → fire), service (fire → resolve), end-to-end latency,
-        # and the update-flush work overlapped inside dispatch→collect
+        # (submit → fire), service (fire → resolve), end-to-end latency;
+        # the lanes' own work is timed by the ``serve.*`` obs spans
         self._h_queue = self.obs.histogram("serve_queue_wait_seconds")
         self._h_service = self.obs.histogram("serve_service_seconds")
         self._h_latency = self.obs.histogram("serve_latency_seconds")
-        self._h_overlap = self.obs.histogram("serve_flush_overlap_seconds")
         self._g_fill = self.obs.gauge("serve_batch_fill")
         self.probe = (self.obs.make_probe(
             index, fraction=self.cfg.recall_probe,
@@ -281,91 +280,91 @@ class ServingEngine:
     def _fire_search(self, reqs: List[SearchRequest], reason: str,
                      overlap_work: Optional[Callable[[], None]] = None
                      ) -> int:
-        B = self.cfg.search_batch
-        vecs = np.stack([r.vector for r in reqs])
-        if len(reqs) < B:
-            vecs = np.concatenate(
-                [vecs, np.zeros((B - len(reqs), vecs.shape[1]),
-                                np.float32)])
-        t_fire = self.clock()
-        obs_on = self.obs.enabled
-        if obs_on:
-            for r in reqs:
-                self._h_queue.record(max(t_fire - r.t_submit, 0.0))
-            self._g_fill.set(len(reqs) / B)
-        if self._can_overlap and self.cfg.overlap:
-            disp = self.index.dispatch_search(vecs, reqs[0].k)
-            if overlap_work is not None:
-                t_w = self.clock()
-                overlap_work()          # runs while the device searches
-                if obs_on:
-                    self._h_overlap.record(max(self.clock() - t_w, 0.0))
-            res = self.index.collect_search(disp)
-        else:
-            res = self.index.search(vecs, reqs[0].k)
-            if overlap_work is not None:
-                overlap_work()
-        now = self.clock()
-        for i, r in enumerate(reqs):
-            r.ticket._resolve(
-                SearchResult(ids=res.ids[i:i + 1],
-                             scores=res.scores[i:i + 1],
-                             seconds=now - r.t_submit), now)
-        if obs_on:
-            self._h_service.record(max(now - t_fire, 0.0))
-            for r in reqs:
-                self._h_latency.record(max(now - r.t_submit, 0.0))
-        if self.probe is not None:
-            # shadow-execute a sampled fraction against exact() — AFTER
-            # the tickets resolved, so the probe is off the hot path
-            self.probe.maybe_probe(vecs[:len(reqs)], reqs[0].k,
-                                   np.asarray(res.ids)[:len(reqs)])
-        self.counters["search_batches"] += 1
-        self.counters["search_requests"] += len(reqs)
-        self.counters["search_padded"] += B - len(reqs)
-        self.counters[f"search_{reason}"] += 1
-        self.batch_log.append(("search", len(reqs), reason))
-        return len(reqs)
+        with self.obs.span("serve.search"):
+            B = self.cfg.search_batch
+            vecs = np.stack([r.vector for r in reqs])
+            if len(reqs) < B:
+                vecs = np.concatenate(
+                    [vecs, np.zeros((B - len(reqs), vecs.shape[1]),
+                                    np.float32)])
+            t_fire = self.clock()
+            obs_on = self.obs.enabled
+            if obs_on:
+                for r in reqs:
+                    self._h_queue.record(max(t_fire - r.t_submit, 0.0))
+                self._g_fill.set(len(reqs) / B)
+            if self._can_overlap and self.cfg.overlap:
+                disp = self.index.dispatch_search(vecs, reqs[0].k)
+                if overlap_work is not None:
+                    with self.obs.span("serve.overlap"):
+                        overlap_work()      # runs while the device searches
+                res = self.index.collect_search(disp)
+            else:
+                res = self.index.search(vecs, reqs[0].k)
+                if overlap_work is not None:
+                    overlap_work()
+            now = self.clock()
+            for i, r in enumerate(reqs):
+                r.ticket._resolve(
+                    SearchResult(ids=res.ids[i:i + 1],
+                                 scores=res.scores[i:i + 1],
+                                 seconds=now - r.t_submit), now)
+            if obs_on:
+                self._h_service.record(max(now - t_fire, 0.0))
+                for r in reqs:
+                    self._h_latency.record(max(now - r.t_submit, 0.0))
+            if self.probe is not None:
+                # shadow-execute a sampled fraction against exact() — AFTER
+                # the tickets resolved, so the probe is off the hot path
+                self.probe.maybe_probe(vecs[:len(reqs)], reqs[0].k,
+                                       np.asarray(res.ids)[:len(reqs)])
+            self.counters["search_batches"] += 1
+            self.counters["search_requests"] += len(reqs)
+            self.counters["search_padded"] += B - len(reqs)
+            self.counters[f"search_{reason}"] += 1
+            self.batch_log.append(("search", len(reqs), reason))
+            return len(reqs)
 
     def _flush_updates(self, reason: str) -> int:
         """Execute up to ``insert_batch`` queued update jobs in FIFO
         order, concatenating consecutive insert submissions into one
         driver call; then run the cadence tick."""
-        budget = self.cfg.insert_batch
-        n_jobs = 0
-        resolved = 0
-        while self._update_q and n_jobs < budget:
-            if self._update_q[0].kind == "insert":
-                group = [self._update_q.popleft()]
-                n_jobs += len(group[0].ids)
-                while (self._update_q and n_jobs < budget
-                       and self._update_q[0].kind == "insert"):
-                    g = self._update_q.popleft()
-                    group.append(g)
-                    n_jobs += len(g.ids)
-                res = self.index.insert(
-                    np.concatenate([g.vecs for g in group]),
-                    np.concatenate([g.ids for g in group]))
-                now = self.clock()
-                for g in group:
-                    g.ticket._resolve(dataclasses.replace(
-                        res, seconds=now - g.ticket.t_submit), now)
-                resolved += len(group)
-            else:
-                job = self._update_q.popleft()
-                n_jobs += len(job.ids)
-                res = self.index.delete(job.ids)
-                now = self.clock()
-                job.ticket._resolve(dataclasses.replace(
-                    res, seconds=now - job.ticket.t_submit), now)
-                resolved += 1
-        self.counters["update_flushes"] += 1
-        self.counters["update_jobs"] += n_jobs
-        self.counters[f"update_{reason}"] += 1
-        self.batch_log.append(("update", n_jobs, reason))
-        self._flushes_since_tick += 1
-        if (self.cfg.tick_every
-                and self._flushes_since_tick >= self.cfg.tick_every):
-            self._flushes_since_tick = 0
-            self.tick()
-        return resolved
+        with self.obs.span("serve.flush"):
+            budget = self.cfg.insert_batch
+            n_jobs = 0
+            resolved = 0
+            while self._update_q and n_jobs < budget:
+                if self._update_q[0].kind == "insert":
+                    group = [self._update_q.popleft()]
+                    n_jobs += len(group[0].ids)
+                    while (self._update_q and n_jobs < budget
+                           and self._update_q[0].kind == "insert"):
+                        g = self._update_q.popleft()
+                        group.append(g)
+                        n_jobs += len(g.ids)
+                    res = self.index.insert(
+                        np.concatenate([g.vecs for g in group]),
+                        np.concatenate([g.ids for g in group]))
+                    now = self.clock()
+                    for g in group:
+                        g.ticket._resolve(dataclasses.replace(
+                            res, seconds=now - g.ticket.t_submit), now)
+                    resolved += len(group)
+                else:
+                    job = self._update_q.popleft()
+                    n_jobs += len(job.ids)
+                    res = self.index.delete(job.ids)
+                    now = self.clock()
+                    job.ticket._resolve(dataclasses.replace(
+                        res, seconds=now - job.ticket.t_submit), now)
+                    resolved += 1
+            self.counters["update_flushes"] += 1
+            self.counters["update_jobs"] += n_jobs
+            self.counters[f"update_{reason}"] += 1
+            self.batch_log.append(("update", n_jobs, reason))
+            self._flushes_since_tick += 1
+            if (self.cfg.tick_every
+                    and self._flushes_since_tick >= self.cfg.tick_every):
+                self._flushes_since_tick = 0
+                self.tick()
+            return resolved

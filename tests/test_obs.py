@@ -3,7 +3,8 @@
 Covers the metrics registry (log-bucket histograms, exposition
 round-trip, the shared driver-stat schema across every engine), the
 structured tracer (reasons on every planner decision), the serving
-request spans, and the sampled live-recall probe.
+request spans, the sampled live-recall probe, and the program spans
+(tick phases, host syncs, their place in a profiler trace).
 """
 from __future__ import annotations
 
@@ -14,8 +15,8 @@ import pytest
 
 from repro.api.registry import list_engines
 from repro.core.types import UBISConfig
-from repro.obs import (DRIVER_STAT_SCHEMA, Histogram, Obs, StatsMap, Tracer,
-                       parse_exposition, required_series)
+from repro.obs import (DRIVER_STAT_SCHEMA, SPAN_NAMES, Histogram, Obs,
+                       StatsMap, Tracer, parse_exposition, required_series)
 
 
 def small_cfg(**kw):
@@ -312,3 +313,311 @@ def test_profile_raises_when_the_trace_cannot_start(tmp_path, monkeypatch):
     with Obs().profile(None):                  # no dir asked: no trace
         ran.append(2)
     assert ran == [2]
+
+
+# ------------------------------------------------------- program spans
+
+
+def test_span_names_are_pinned():
+    assert SPAN_NAMES == (
+        "tick", "tick.execute", "tick.drain", "tick.mark", "tick.gc",
+        "tick.retrain", "tick.tier",
+        "insert", "delete", "search.dispatch", "search.collect",
+        "sync.round", "sync.drain", "sync.detect", "sync.gc",
+        "sync.retrain", "sync.insert", "sync.delete", "sync.search",
+        "serve.search", "serve.flush", "serve.overlap")
+    obs = Obs()
+    with obs.span("tick") as sp:
+        pass
+    assert sp.seconds >= 0.0
+    assert obs.snapshot()["span_tick_seconds"]["count"] == 1
+    with pytest.raises(ValueError, match="unknown span"):
+        obs.span("tick.unlisted")
+
+
+@pytest.fixture
+def ann_log(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``: the returned list
+    logs each enter and exit, in order."""
+    from repro.obs import span
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(span, "TraceAnnotation", Ann)
+    return log
+
+
+def _pq_driver(obs=None, **kw):
+    from repro.core.driver import UBISDriver
+    cfg = small_cfg(use_pq=True, pq_m=4, pq_ksub=16)
+    return UBISDriver(cfg, seeds(), round_size=64, bg_ops_per_round=4,
+                      pq_retrain_every=3, obs=obs, **kw)
+
+
+def _ticks(log):
+    """The annotation log cut into one list per ``ubis.tick``."""
+    out, cur, depth = [], None, 0
+    for ev, name in log:
+        if ev == "enter" and name == "ubis.tick" and depth == 0:
+            cur = []
+        if cur is not None:
+            cur.append((ev, name))
+            depth += 1 if ev == "enter" else -1
+            if depth == 0:
+                out.append(cur)
+                cur = None
+    return out
+
+
+def test_tick_opens_its_phases_nested_and_in_order(ann_log):
+    drv = _pq_driver()
+    drv.insert(seeds(200, seed=1), np.arange(200), tick_between=False)
+    ann_log.clear()
+    for _ in range(7):
+        drv.tick()
+    ticks = _ticks(ann_log)
+    assert len(ticks) == 7
+    for i, t in enumerate(ticks):
+        assert t[0] == ("enter", "ubis.tick") and t[-1] == ("exit",
+                                                            "ubis.tick")
+        # the phases are the tick's children, each closed before the
+        # next opens, in the order a tick runs them
+        depth, children = 0, []
+        for ev, name in t[1:-1]:
+            if ev == "enter":
+                if depth == 0:
+                    children.append(name[len("ubis."):])
+                depth += 1
+            else:
+                depth -= 1
+        want = ["tick.execute", "tick.drain", "tick.mark", "tick.gc"]
+        if (i + 1) % 3 == 0:                   # pq_retrain_every=3
+            want.append("tick.retrain")
+        assert children == want, (i, children)
+    snap = drv.obs.snapshot()
+    assert snap["span_tick_retrain_seconds"]["count"] == 2
+    assert drv.stats["pq_retrains"] == 2
+
+
+@pytest.mark.parametrize("mode", ["fused", "spfresh", "tier",
+                                  "tier_async"])
+def test_tick_phases_in_each_driver_mode(ann_log, mode):
+    """The fused device mark, SPFresh's strict triggers (no cache, so no
+    drain) and the cold tier (a last ``tick.tier`` phase, sync or
+    dispatched at the tick's start) each open their phases in order,
+    and the mark reads the device once a tick."""
+    from repro.core.driver import UBISDriver
+    cfg_kw, drv_kw = {}, {}
+    if mode == "fused":
+        drv_kw["fused_tick"] = True
+    elif mode == "spfresh":
+        cfg_kw["mode"] = "spfresh"
+    else:
+        cfg_kw.update(use_tier=True, tier_hot_max=0, max_postings=64,
+                      use_pq=True, pq_m=4, pq_ksub=16)
+        drv_kw["tier_async"] = mode == "tier_async"
+    drv = UBISDriver(small_cfg(**cfg_kw), seeds(), round_size=64,
+                     bg_ops_per_round=4, **drv_kw)
+    drv.insert(seeds(200, seed=1), np.arange(200), tick_between=False)
+    ann_log.clear()
+    for _ in range(4):
+        drv.tick()
+    want = ["tick.execute", "tick.drain", "tick.mark", "tick.gc"]
+    if mode == "spfresh":
+        want.remove("tick.drain")
+    if mode.startswith("tier"):
+        want.append("tick.tier")
+    ticks = _ticks(ann_log)
+    assert len(ticks) == 4
+    for t in ticks:
+        depth, children = 0, []
+        for ev, name in t[1:-1]:
+            if ev == "enter" and depth == 0:
+                children.append(name[len("ubis."):])
+            depth += 1 if ev == "enter" else -1
+        assert children == want, (mode, children)
+        assert sum(1 for ev, n in t
+                   if ev == "enter" and n == "ubis.sync.detect") == 1
+
+
+def test_span_counts_equal_the_calls_and_feed_the_timings():
+    from repro.core.driver import UBISDriver
+    drv = UBISDriver(small_cfg(), seeds(), round_size=64)
+    for i in range(3):
+        drv.insert(seeds(40, seed=10 + i), np.arange(40 * i, 40 * i + 40),
+                   tick_between=False)
+    drv.delete(np.arange(10))
+    drv.delete(np.arange(10, 20))
+    for _ in range(4):
+        drv.tick()
+    drv.search(seeds(8, seed=3), 4)
+    snap = drv.obs.snapshot()
+
+    def h(name):
+        return snap[f"span_{name.replace('.', '_')}_seconds"]
+    assert h("insert")["count"] == 3 and h("delete")["count"] == 2
+    assert h("tick")["count"] == 4
+    assert h("search.dispatch")["count"] == h("search.collect")["count"] == 1
+    # the stats and the events read the spans, not a second clock
+    s = drv.stats
+    assert s["insert_time"] == pytest.approx(h("insert")["sum"])
+    assert s["delete_time"] == pytest.approx(h("delete")["sum"])
+    assert s["bg_time"] == pytest.approx(h("tick")["sum"])
+    assert s["bg_exec_time"] == pytest.approx(h("tick.execute")["sum"])
+    ev_ticks = sum(e["seconds"] for e in drv.obs.events("tick"))
+    assert ev_ticks == pytest.approx(h("tick")["sum"], abs=1e-5)
+    # the exposition carries one histogram per span
+    series = parse_exposition(drv.obs.to_prometheus())
+    assert series["span_tick_execute_seconds_count"] == 4.0
+
+
+def test_disabled_plane_records_no_span(ann_log):
+    from repro.core.driver import UBISDriver
+    obs = Obs(enabled=False)
+    drv = UBISDriver(small_cfg(), seeds(), round_size=64, obs=obs)
+    drv.insert(seeds(40, seed=1), np.arange(40))
+    drv.tick()
+    drv.delete(np.arange(5))
+    drv.search(seeds(4, seed=2), 4)
+    assert ann_log == []
+    assert not [k for k in obs.snapshot() if k.startswith("span_")]
+    assert len(obs.tracer) == 0
+    # the spans still time their block: the stats plane stays live
+    assert drv.stats["insert_time"] > 0 and drv.stats["bg_time"] > 0
+
+
+def _sync_counts(obs):
+    snap = obs.snapshot()
+    return {n: snap.get(f"span_{n.replace('.', '_')}_seconds",
+                        {"count": 0})["count"]
+            for n in SPAN_NAMES if n.startswith("sync.")}
+
+
+def test_sync_counts_per_tick_and_per_update_round():
+    """The blocking reads, site by site.  An insert or delete reads its
+    round's results once per ``round_size`` rows plus one final wait; a
+    tick reads the round's counters when a marked batch ran, the cache
+    count (and the drained count when the cache held vectors), the
+    detector's masks with the lengths, the version (and the reclaimed
+    count past ``gc_lag``), and on the retrain cadence the active slot
+    and the new generation."""
+    drv = _pq_driver(gc_lag=4)
+    obs = drv.obs
+    before = _sync_counts(obs)
+    drv.insert(seeds(200, seed=1), np.arange(200), tick_between=False)
+    after = _sync_counts(obs)
+    assert after["sync.insert"] - before["sync.insert"] == 4 + 1
+    assert sum(after.values()) - sum(before.values()) == 5
+    per_tick = []
+    for i in range(9):
+        had_marked = bool(drv._marked)
+        cache_n = int(np.asarray(drv.state.cache_valid).sum())
+        before = _sync_counts(obs)
+        r = drv.tick()
+        after = _sync_counts(obs)
+        d = {k: after[k] - before[k] for k in after}
+        ver = int(drv.state.global_version)
+        assert d["sync.round"] == int(had_marked), i
+        assert d["sync.drain"] == 1 + int(cache_n > 0 or r.drained > 0), i
+        assert d["sync.detect"] == 1
+        assert d["sync.gc"] == 1 + int(ver > drv.gc_lag), i
+        assert d["sync.retrain"] == (2 if (i + 1) % 3 == 0 else 0), i
+        assert d["sync.insert"] == d["sync.delete"] == d["sync.search"] == 0
+        per_tick.append(sum(d.values()))
+    # 3 (nothing to run) to 8 (a marked batch, a full cache, GC and a
+    # retrain)
+    assert min(per_tick) >= 3 and max(per_tick) <= 8
+    assert max(per_tick) > min(per_tick)
+    before = _sync_counts(obs)
+    drv.delete(np.arange(100))
+    after = _sync_counts(obs)
+    assert after["sync.delete"] - before["sync.delete"] == 2 + 1
+    assert sum(after.values()) - sum(before.values()) == 3
+    before = _sync_counts(obs)
+    drv.search(seeds(8, seed=3), 4)
+    after = _sync_counts(obs)
+    assert after["sync.search"] - before["sync.search"] == 1
+    assert sum(after.values()) - sum(before.values()) == 1
+
+
+def test_profiler_trace_holds_the_tick_inside_the_callers_span(tmp_path):
+    """On the profiler's clock: ``ubis.tick`` sits inside the caller's
+    ``bench.tick`` annotation, and its phases inside it."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+    from repro.core.driver import UBISDriver
+    drv = UBISDriver(small_cfg(), seeds(), round_size=64)
+    drv.insert(seeds(100, seed=1), np.arange(100), tick_between=False)
+    drv.tick()                                 # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench.tick"):
+            drv.tick()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    ev = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+          for p in ProfileData.from_file(path).planes for ln in p.lines
+          for e in ln.events if e.name.startswith(("bench.", "ubis."))]
+    (outer,) = [e for e in ev if e[0] == "bench.tick"]
+    (tick,) = [e for e in ev if e[0] == "ubis.tick"]
+    assert outer[1] <= tick[1] and tick[2] <= outer[2]
+    phases = sorted((e for e in ev if e[0].startswith("ubis.tick.")),
+                    key=lambda e: e[1])
+    assert [e[0] for e in phases] == ["ubis.tick.execute", "ubis.tick.drain",
+                                      "ubis.tick.mark", "ubis.tick.gc"]
+    assert all(tick[1] <= e[1] and e[2] <= tick[2] for e in phases)
+    assert any(e[0].startswith("ubis.sync.") for e in ev)
+
+
+def test_engine_spans_cover_its_lanes_and_the_overlap():
+    from repro.api.registry import make_index
+    from repro.serving.engine import ServingConfig, ServingEngine
+    idx = make_index("ubis", small_cfg(), seeds(), round_size=64)
+    eng = ServingEngine(idx, ServingConfig(
+        search_batch=4, search_deadline_s=0.0, insert_deadline_s=0.0,
+        tick_every=1))
+    tickets = [eng.submit_insert(seeds(16, seed=4), np.arange(16))]
+    tickets += [eng.submit_search(q[None], 4) for q in seeds(4, seed=5)]
+    assert _drain(eng, tickets)
+    snap = eng.obs.snapshot()
+    # one pump: the flush (and its tick) ran inside the search's
+    # dispatch -> collect window
+    assert snap["span_serve_search_seconds"]["count"] == 1
+    assert snap["span_serve_overlap_seconds"]["count"] == 1
+    assert snap["span_serve_flush_seconds"]["count"] == 1
+    assert snap["span_tick_seconds"]["count"] == 1
+    assert "serve_flush_overlap_seconds" not in snap
+    assert (snap["span_serve_flush_seconds"]["sum"]
+            <= snap["span_serve_overlap_seconds"]["sum"]
+            <= snap["span_serve_search_seconds"]["sum"])
+
+
+def test_serve_profile_dir_captures_one_trace(tmp_path):
+    """``--obs-profile-dir``: the engine's first working pump is the one
+    capture (a driver-level capture inside it used to raise "Profile has
+    already been started")."""
+    import glob
+
+    from repro.launch.serve import RetrievalServer, ServeConfig
+    prof = tmp_path / "prof"
+    srv = RetrievalServer(
+        ServeConfig(embed_dim=16, obs_profile_dir=str(prof)),
+        index_cfg=small_cfg(), seed_vectors=seeds())
+    ids = srv.ingest_vectors(seeds(32, seed=1))
+    res = srv.query_vectors(seeds(2, seed=1)[:1], k=4)
+    assert res.ids.shape == (1, 4) and len(ids) == 32
+    assert len(glob.glob(str(prof / "**" / "*.xplane.pb"),
+                         recursive=True)) == 1
