@@ -10,11 +10,12 @@ selected backend for an achieved-vs-predicted column.
 
 Two honesty metrics matter here:
 
-* ``useful_flops`` vs ``flops``: the PQ ADC kernels execute a one-hot
-  (C, ksub) @ (ksub, 1) matmul per subspace on the MXU — ``2*C*ksub``
-  executed FLOPs for ``C`` useful adds.  The executed count feeds the
-  compute-time estimate; the useful count is what recall per second
-  actually buys.
+* ``useful_flops`` vs ``flops``: the executed count adds the work a
+  kernel spends beyond the answer (the fused top-k's selection rounds)
+  and feeds the compute-time estimate; the useful count is what recall
+  per second actually buys.  The PQ ADC scan looks its tables up by a
+  lane gather, so it executes ``m`` adds per candidate and no MXU
+  FLOPs.
 * fused vs unfused bytes: the ``*_topk`` kernels write ``2*Q*k`` scalars
   instead of a (Q, M) / (Q, P, C) score tensor; the rows make the HBM
   traffic that fusion removes explicit.
@@ -137,15 +138,14 @@ def build_cases(p: Dict, backend: str) -> List[Dict]:
         lambda: ops.posting_scan_topk(q, vecs, slot_valid, vis, probe,
                                       k=k, backend=backend))
 
-    # --- quant plane: ADC scans (one-hot MXU trick: 2*C*ksub executed
-    # FLOPs per (query, probe, subspace) for C useful adds) --------------
-    adc_exec = 2.0 * Q * P * m * C * ksub
-    adc_useful = 2.0 * Q * P * m * C
+    # --- quant plane: ADC scan (lane-gather lookups, m adds per
+    # candidate; one k-round selection over the P*C candidates a query)
+    adc_adds = 1.0 * Q * P * m * C
     adc_bytes = Q * P * (m * C + f32 * m * ksub)  # codes u8 + lut tile
     add(_row("pq_scan_topk", "pq_scan",
              f"Q={Q} P={P} C={C} m={m} ksub={ksub} k={k}",
-             flops=adc_exec + 1.0 * Q * P * k * C,
-             useful_flops=adc_useful,
+             flops=adc_adds + 1.0 * Q * k * P * C,
+             useful_flops=adc_adds,
              bytes_=adc_bytes + f32 * 2 * Q * k),
         lambda: ops.pq_scan_topk(luts, codes, pslot, slot_valid, vis,
                                  probe, k=k, backend=backend))

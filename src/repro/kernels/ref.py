@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for every Pallas kernel in this package (and one
+numpy twin that pins a summation order).
 
 Each function is the semantic ground truth: tests assert the Pallas
 kernels (run with ``interpret=True`` on CPU) match these to tolerance,
@@ -14,6 +15,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 BIG = 1e30  # masked-score sentinel shared with the Pallas kernels
 # f32 matmuls at full precision, as in the Pallas kernels (a TPU's
@@ -169,6 +171,29 @@ def pq_scan_topk(luts: jax.Array, codes: jax.Array, slot: jax.Array,
                 + jnp.arange(C, dtype=jnp.int32)[None, None, :])
     cand = jnp.take_along_axis(cand_all.reshape(Q, P * C), pos, axis=1)
     return -neg, cand.astype(jnp.int32)
+
+
+def np_pq_scan_topk(luts, codes, slot, valid, qp_ok, probe, k: int):
+    """numpy twin of :func:`pq_scan_topk` that fixes the summation order:
+    the subspace sum in float32, j = 0..m-1 in order (what the Pallas
+    kernel computes), so the two agree bit for bit on non-integer
+    tables too, where ``jnp.sum``'s order is XLA's to choose.  Same
+    arguments and results, as numpy arrays."""
+    luts, codes, slot = np.asarray(luts), np.asarray(codes), np.asarray(slot)
+    probe = np.asarray(probe)
+    Q, P = probe.shape
+    m, C = codes.shape[1:]
+    tab = luts[np.arange(Q)[:, None], slot[probe]]        # (Q, P, m, ksub)
+    cd = codes[probe].astype(np.int64)                    # (Q, P, m, C)
+    acc = np.zeros((Q, P, C), np.float32)
+    for j in range(m):
+        acc = acc + np.take_along_axis(tab[:, :, j], cd[:, :, j], axis=2)
+    ok = np.asarray(valid)[probe] & (np.asarray(qp_ok) != 0)[:, :, None]
+    s = np.where(ok, acc, np.float32(BIG)).reshape(Q, P * C)
+    pos = np.argsort(s, axis=1, kind="stable")[:, :k]
+    cand = (probe[:, :, None] * C + np.arange(C)).reshape(Q, P * C)
+    return (np.take_along_axis(s, pos, 1),
+            np.take_along_axis(cand, pos, 1).astype(np.int32))
 
 
 def posting_scan_topk(queries: jax.Array, vectors: jax.Array,

@@ -194,7 +194,15 @@ def test_posting_scan_topk_sparse_and_qp_ok(rng):
 
 @pytest.mark.parametrize("Q,V,m,ksub,M,C,P,k", [(1, 2, 8, 128, 12, 128, 1, 3),
                                                 (6, 2, 8, 128, 12, 128, 4, 20),
-                                                (3, 3, 4, 256, 9, 128, 5, 64)])
+                                                (3, 3, 4, 256, 9, 128, 5, 64),
+                                                # the SIFT1M deployment's
+                                                # m, ksub, C, nprobe, rerank
+                                                (2, 2, 16, 256, 40, 96, 32,
+                                                 64),
+                                                # two query groups, the
+                                                # last one short
+                                                (11, 2, 8, 256, 12, 96, 4,
+                                                 20)])
 def test_pq_scan_topk_parity(Q, V, m, ksub, M, C, P, k, rng):
     luts = _int_normal(rng, (Q, V, m, ksub), lo=0, hi=8)
     codes = jnp.asarray(rng.integers(0, ksub, (M, m, C)).astype(np.uint8))
@@ -227,6 +235,50 @@ def test_pq_scan_topk_all_invalid(rng):
     assert np.all(np.asarray(s1) >= ref.BIG / 2)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+
+
+@pytest.mark.parametrize("Q,M,C,P,k,live", [(4, 6, 128, 3, 3 * 128, 0.05),
+                                            (3, 40, 96, 32, 64, 0.01)])
+def test_pq_scan_topk_sparse_and_qp_ok(Q, M, C, P, k, live, rng):
+    """Fewer live candidates than k: the tail is BIG on both backends,
+    in the ref twin's tie order; a per-(query, probe) ownership mask is
+    honoured."""
+    V, m, ksub = 2, 16, 256
+    luts = _int_normal(rng, (Q, V, m, ksub), lo=0, hi=8)
+    codes = jnp.asarray(rng.integers(0, ksub, (M, m, C)).astype(np.uint8))
+    slot = jnp.asarray(rng.integers(0, V, (M,)).astype(np.int32))
+    slot_valid = jnp.asarray(rng.random((M, C)) < live)
+    vis = jnp.ones((M,), bool)
+    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
+    qp_ok = jnp.asarray(rng.integers(0, 2, (Q, P)).astype(np.int32))
+    s1, i1 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
+                              k=k, qp_ok=qp_ok, backend="off")
+    s2, i2 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
+                              k=k, qp_ok=qp_ok, backend="on")
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    assert np.any(np.asarray(s1) >= ref.BIG / 2)  # sparse -> BIG tail
+
+
+@pytest.mark.parametrize("Q,V,m,ksub,M,C,P,k", [(3, 2, 16, 256, 40, 96, 32,
+                                                 64),
+                                                (3, 3, 8, 200, 7, 133, 3, 64)])
+def test_pq_scan_topk_float_tables(Q, V, m, ksub, M, C, P, k, rng):
+    """Non-integer tables: the kernel's scores are the float32 sum over
+    subspaces in order, bit for bit, with the same ids."""
+    luts = jnp.asarray(rng.normal(size=(Q, V, m, ksub)).astype(np.float32))
+    codes = jnp.asarray(rng.integers(0, ksub, (M, m, C)).astype(np.uint8))
+    slot = jnp.asarray(rng.integers(0, V, (M,)).astype(np.int32))
+    slot_valid = jnp.asarray(rng.random((M, C)) > 0.3)
+    vis = jnp.asarray(rng.random(M) > 0.2)
+    probe = jnp.asarray(rng.integers(0, M, (Q, P)).astype(np.int32))
+    s2, i2 = ops.pq_scan_topk(luts, codes, slot, slot_valid, vis, probe,
+                              k=k, backend="on")
+    s3, i3 = ref.np_pq_scan_topk(
+        luts, codes, slot, np.asarray(slot_valid) & np.asarray(vis)[:, None],
+        np.ones((Q, P), np.int32), probe, k)
+    np.testing.assert_array_equal(np.asarray(s2), s3)
+    np.testing.assert_array_equal(np.asarray(i2), i3)
 
 
 # -- alignment-free sweeps: real-world dims (96/100/300), odd posting

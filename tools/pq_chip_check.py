@@ -20,8 +20,18 @@ small sizes on the CPU):
 Prints one line per check with the share of codes equal to the numpy
 encode; exits 1 when any share is below ``--min-agree``.
 
+``--scan`` instead times ``ops.pq_scan_topk`` alone at the PQ search
+batch's shapes (64 queries x nprobe 32, m=16 x 256, 96 slots, the
+rerank's k=64, every posting of the pool, ``pq_versions`` codebook
+slots), after checking its output bit for bit: against the jnp twin on
+integer tables, and against a numpy float32 sum over subspaces in
+order on non-integer ones.  Prints the milliseconds per call (median
+and quartiles of single calls waited on one by one, and the mean of a
+back-to-back run); exits 1 on any mismatch.
+
     python tools/pq_chip_check.py            # smoke shapes (run on a TPU)
     python tools/pq_chip_check.py --small    # 1,024 postings, any backend
+    python tools/pq_chip_check.py --scan     # the ADC kernel alone
 """
 from __future__ import annotations
 
@@ -58,6 +68,76 @@ def agree(codes: np.ndarray, cbs: np.ndarray, slots: np.ndarray,
     return hits / total
 
 
+def scan_check(args) -> int:
+    """Time ``ops.pq_scan_topk`` alone at the search batch's shapes,
+    after checking it bit for bit (see the module docstring)."""
+    import time
+    import jax
+    import jax.numpy as jnp
+    import chip_smoke
+    from repro.kernels import ops, ref
+
+    M = 1024 if args.small else 32768
+    cfg = chip_smoke.sift_config(max_postings=M)
+    Q, P, m, ksub = 64, cfg.nprobe, cfg.pq_m, cfg.pq_ksub
+    C, k, V = cfg.capacity, cfg.rerank_k, cfg.pq_versions
+    print(f"pq_chip_check --scan: backend={jax.default_backend()} "
+          f"device={jax.devices()[0].device_kind} Q={Q} P={P} m={m} "
+          f"ksub={ksub} C={C} k={k} M={M} V={V}", flush=True)
+    rng = np.random.default_rng(args.seed)
+    codes = rng.integers(0, ksub, (M, m, C)).astype(np.uint8)
+    slot = rng.integers(0, V, M).astype(np.int32)
+    slot_valid = rng.random((M, C)) < 0.8
+    vis = rng.random(M) < 0.95
+    probe = np.stack([rng.choice(M, P, replace=False)
+                      for _ in range(Q)]).astype(np.int32)
+    dev = [jnp.asarray(x) for x in (codes, slot, slot_valid, vis, probe)]
+
+    def call(backend):
+        return jax.jit(lambda lut, cd, sl, sv, vs, pr: ops.pq_scan_topk(
+            lut, cd, sl, sv, vs, pr, k=k, backend=backend))
+
+    kernel, twin = call(args.backend), call("off")
+    int_luts = rng.integers(0, 4096, (Q, V, m, ksub)).astype(np.float32)
+    float_luts = (rng.random((Q, V, m, ksub)) * 4096).astype(np.float32)
+    ok = True
+    s1, i1 = kernel(jnp.asarray(int_luts), *dev)
+    s2, i2 = twin(jnp.asarray(int_luts), *dev)
+    same = (np.array_equal(np.asarray(s1), np.asarray(s2))
+            and np.array_equal(np.asarray(i1), np.asarray(i2)))
+    print(f"  integer tables vs the jnp twin: bit-identical={same}",
+          flush=True)
+    ok &= same
+    s1, i1 = kernel(jnp.asarray(float_luts), *dev)
+    s3, i3 = ref.np_pq_scan_topk(float_luts, codes, slot,
+                                 slot_valid & vis[:, None],
+                                 np.ones((Q, P), np.int32), probe, k)
+    same = (np.array_equal(np.asarray(s1), s3)
+            and np.array_equal(np.asarray(i1), i3))
+    print(f"  float tables vs numpy in-order f32 sum: bit-identical={same}",
+          flush=True)
+    ok &= same
+
+    luts = jnp.asarray(float_luts)
+    for _ in range(3):
+        jax.block_until_ready(kernel(luts, *dev))
+    one = []
+    for _ in range(args.calls):
+        t0 = time.perf_counter()
+        jax.block_until_ready(kernel(luts, *dev))
+        one.append((time.perf_counter() - t0) * 1e3)
+    t0 = time.perf_counter()
+    outs = [kernel(luts, *dev) for _ in range(args.calls)]
+    jax.block_until_ready(outs)
+    back = (time.perf_counter() - t0) * 1e3 / args.calls
+    q1, q2, q3 = np.percentile(one, [25, 50, 75])
+    print(f"  ms per call: median {q2:.4f} (quartiles {q1:.4f}-{q3:.4f}, "
+          f"{args.calls} calls waited on one by one); back to back "
+          f"{back:.4f}", flush=True)
+    print(f"pq_chip_check --scan: {'ok' if ok else 'MISMATCH'}", flush=True)
+    return 0 if ok else 1
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--small", action="store_true")
@@ -65,9 +145,17 @@ def main(argv=None) -> int:
     ap.add_argument("--sample", type=int, default=96,
                     help="postings compared with numpy per check")
     ap.add_argument("--min-agree", type=float, default=0.97)
+    ap.add_argument("--scan", action="store_true",
+                    help="time and check the ADC scan kernel alone")
+    ap.add_argument("--calls", type=int, default=50,
+                    help="timed kernel calls (--scan)")
+    ap.add_argument("--backend", default="auto",
+                    help="kernel backend of the timed calls (--scan)")
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT))
     sys.path.insert(0, str(ROOT / "src"))
+    if args.scan:
+        return scan_check(args)
     import jax
     import jax.numpy as jnp
     import chip_smoke
